@@ -11,8 +11,8 @@ overwritten: each run refreshes the per-layer snapshot (now including
 the batched multi-tile-row fused variant) and APPENDS a timestamped
 git-SHA entry to ``BENCH_conv.json["trajectory"]``, so the accumulated
 history rides the committed file across PRs.  ``scaleout`` appends the
-SPMD per-shard-count rows to the same artifact (forced host-device mesh
-on single-device hosts); ``serving`` appends the open-loop
+SPMD per-shard-count rows to the same artifact (it needs two or more
+devices in this process); ``serving`` appends the open-loop
 continuous-batching SLO rows (``repro.serve`` engine, p50/p95/p99 +
 goodput + occupancy + cache hit rate) under the ``"serving"`` key;
 ``chaos`` appends goodput/SLO under injected fault rates plus breaker
@@ -27,6 +27,11 @@ import time
 
 
 def main() -> None:
+    import os
+
+    from repro.runtime import use_compilation_cache
+    use_compilation_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks import (appendixB_iterative, chaos,
                             fig4_accuracy_vs_bops, fig5_layer_mse,
                             roofline, scaleout, serving,

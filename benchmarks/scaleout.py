@@ -6,26 +6,24 @@ over 'data' or C_out over 'model', and appends per-shard-count rows to
 ``table3_throughput`` — the artifact CI uploads to track the perf
 trajectory.
 
-Needs multiple devices.  When the process owns only one, it re-execs
-itself in a subprocess with a *forced host-device mesh*
-(``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — CPU "devices"
-are host threads, so intra-host speedup is NOT the point; the rows track
-per-shard correctness (every row asserts bit-identity against the
-single-device backend) and the shard_map dispatch overhead trajectory).
-On real multi-chip hosts the same sweep measures actual scaling.
+The sweep runs in this process on whatever devices JAX has, and refuses
+with a message when there are fewer than two: it never starts a second
+JAX process, which on a TPU host would contend for the chips this one
+holds.  On a CPU host, give the process forced host devices before it
+starts (CPU "devices" are host threads, so intra-host speedup is NOT the
+point there; the rows track per-shard correctness — every row asserts
+bit-identity against the single-device backend — and the shard_map
+dispatch overhead).  On a multi-chip host the same sweep measures actual
+scaling.
 
-  PYTHONPATH=src python -m benchmarks.run scaleout
-  REPRO_SCALEOUT_DEVICES=4 PYTHONPATH=src python -m benchmarks.scaleout
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m benchmarks.run scaleout
 """
 import json
 import os
-import subprocess
-import sys
-import tempfile
 
 import numpy as np
 
-DEVICES = int(os.environ.get("REPRO_SCALEOUT_DEVICES", "8"))
 BENCH_PATH = os.environ.get("REPRO_BENCH_OUT", "BENCH_conv.json")
 
 
@@ -83,29 +81,16 @@ def _sweep(log) -> list:
     return rows
 
 
-def _respawn(log) -> list:
-    """Re-exec in a subprocess with forced host devices; collect rows."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={DEVICES}"
-                        ).strip()
-    fd, out = tempfile.mkstemp(suffix=".json")
-    os.close(fd)
-    try:
-        log(f"scaleout: single-device host, re-exec with {DEVICES} "
-            f"forced host devices")
-        subprocess.run([sys.executable, "-m", "benchmarks.scaleout",
-                        "--worker", out], env=env, check=True)
-        with open(out) as f:
-            return json.load(f)
-    finally:
-        os.unlink(out)
-
-
 def run(log=print, bench_path: str = None) -> dict:
     import jax
     bench_path = bench_path or BENCH_PATH
-    rows = _sweep(log) if len(jax.devices()) >= 2 else _respawn(log)
+    devices = jax.devices()
+    if len(devices) < 2:
+        raise SystemExit(
+            f"scaleout needs at least 2 devices, JAX has {len(devices)} "
+            f"({devices[0].platform}); on a CPU host start Python with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    rows = _sweep(log)
     bench = {}
     if os.path.exists(bench_path):
         try:
@@ -117,7 +102,8 @@ def run(log=print, bench_path: str = None) -> dict:
         "workload": {"batch": 8, "cin": 64, "cout": 128, "algo": "sfc6_6",
                      "quant": "int8", "spatial_cap":
                      int(os.environ.get("REPRO_BENCH_SPATIAL_CAP", "28"))},
-        "forced_host_devices": DEVICES,
+        "devices": len(devices),
+        "platform": devices[0].platform,
         "rows": rows,
     }
     with open(bench_path, "w") as f:
@@ -127,9 +113,4 @@ def run(log=print, bench_path: str = None) -> dict:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 2 and sys.argv[1] == "--worker":
-        rows = _sweep(print)
-        with open(sys.argv[2], "w") as f:
-            json.dump(rows, f)
-    else:
-        run()
+    run()

@@ -2,13 +2,13 @@
 
 The fused kernel (``repro.kernels.sfc_fused``) exposes its complete
 launch geometry as data (:class:`~repro.kernels.sfc_fused.FusedGeometry`)
-— grid, channel blocking, Unblocked strip index maps, scratch set, DMA
+— grid, channel blocking, strip DMA windows, scratch set, DMA
 pipeline constants.  This module verifies, *without launching anything*:
 
   * **VMEM budget** — the per-grid-step footprint of the geometry fits
     ``VMEM_LIMIT_BYTES`` (a kernel that exceeds it spills or fails to
     allocate on real hardware; interpret mode would happily "run" it);
-  * **strip bounds** — every Unblocked strip read (including the ragged
+  * **strip bounds** — every strip DMA read (including the ragged
     last strip group of each image column) lands inside the padded HBM
     extents, and the blocked channel/output axes tile their padded
     extents exactly;

@@ -5,9 +5,10 @@ Two backends ship today, both consuming the same ``PreparedWeights``:
   * ``reference`` — pure jnp, built from the ``repro.core.conv2d``
     primitives.  Supports elementwise hooks (dynamic fake quantization,
     PTQ calibration observers) and is the numerical oracle.
-  * ``pallas``    — the ``repro.kernels`` TPU kernels (interpret mode on
-    CPU).  Static precision only: fp, or int8 with PTQ-calibrated scales
-    baked into the prepared weights.
+  * ``pallas``    — the ``repro.kernels`` TPU kernels, compiled on a TPU
+    and run in the Pallas interpreter elsewhere (``ConvPlan.interpret``,
+    derived from the platform).  Static precision only: fp, or int8 with
+    PTQ-calibrated scales baked into the prepared weights.
 
 2-D depthwise specs run the transform-domain *elementwise* stage instead
 of the t^2 matmuls on both backends (jnp broadcast on ``reference``; the
@@ -88,11 +89,14 @@ class ReferenceBackend:
         if prep.quantized:
             # static-int8 simulation with the same scales/integer grid as
             # the Pallas datapath: quantize tx with the calibrated
-            # frequency scales, use the offline-quantized weights.
+            # frequency scales (the kernels' reciprocal multiply), use the
+            # offline-quantized weights.
             qc = plan.spec.quant
             s_act = prep.act_scale[None, None, None, :, :, None]
-            tx = fq.dequantize(
-                fq.quantize(tx, s_act, qc.bits_act), s_act)
+            inv = c2d.reciprocal_scale(prep.act_scale)
+            tx = fq.dequantize(c2d.quantize_slab(
+                tx, inv[None, None, None, :, :, None],
+                fq.qmax_for_bits(qc.bits_act)), s_act)
             tw = (prep.wq.astype(jnp.float32).reshape(tw.shape)
                   * prep.w_scale[:, :, None, :]).astype(tx.dtype)
         elif elementwise_hook is not None:
@@ -174,15 +178,15 @@ class PallasBackend:
             return _add_bias(y, bias)
         from repro.kernels.sfc_inverse import sfc_inverse
         from repro.kernels.sfc_transform import sfc_transform
-        bt, _, at = c2d.transform_matrices(algo, x.dtype.name)
         tiles, geom = ops.extract_tiles(x, algo, plan.spec.padding)
-        tx = sfc_transform(tiles, bt, interpret=plan.interpret)
+        tx = sfc_transform(tiles, algo, interpret=plan.interpret)
         if depthwise:
             # transform-domain elementwise stage (tw (t, t, 1, C))
-            ty = tx * prep.tw[None, :, :, 0, :].astype(x.dtype)
+            ty = tx * prep.tw[:, :, 0, None, :].astype(x.dtype)
         else:
-            ty = jnp.einsum("ntuc,tuco->ntuo", tx, prep.tw.astype(x.dtype))
-        y_tiles = sfc_inverse(ty, at, interpret=plan.interpret)
+            ty = jnp.einsum("tunc,tuco->tuno", tx, prep.tw.astype(x.dtype),
+                            precision=jax.lax.Precision.HIGHEST)
+        y_tiles = sfc_inverse(ty, algo, interpret=plan.interpret)
         return _add_bias(ops.untile(y_tiles, algo, geom), bias)
 
 

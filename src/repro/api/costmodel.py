@@ -56,6 +56,7 @@ import numpy as np
 
 from repro.api.spec import ConvSpec
 from repro.quant.bops import direct_conv_bops, fastconv_bops
+from repro.runtime import resolve_interpret
 
 _ENV_CACHE = "REPRO_COSTMODEL_CACHE"
 _DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
@@ -150,15 +151,16 @@ def _save() -> None:
                 RuntimeWarning, stacklevel=3)
 
 
-def _key(backend: str, interpret: bool) -> str:
+def _key(backend: str, interpret: Optional[bool]) -> str:
     # device platform is part of the key for the same reason as the
     # timing cache: interpret-mode CPU coefficients must never price
     # compiled-TPU plans
-    return f"{backend}|{jax.default_backend()}|i{int(interpret)}"
+    return (f"{backend}|{jax.default_backend()}"
+            f"|i{int(resolve_interpret(interpret))}")
 
 
-def coefficients(backend: str = "pallas",
-                 interpret: bool = True) -> Optional[Dict[str, List[float]]]:
+def coefficients(backend: str = "pallas", interpret: Optional[bool] = None
+                 ) -> Optional[Dict[str, List[float]]]:
     """Fitted per-datapath coefficient vectors, or None when unfitted."""
     entry = _load().get(_key(backend, interpret))
     if not entry:
@@ -167,12 +169,14 @@ def coefficients(backend: str = "pallas",
             for dp in N_FEATURES if dp in entry}
 
 
-def is_fitted(backend: str = "pallas", interpret: bool = True) -> bool:
+def is_fitted(backend: str = "pallas",
+              interpret: Optional[bool] = None) -> bool:
     return bool(coefficients(backend, interpret))
 
 
 def set_coefficients(coefs: Dict[str, Sequence[float]],
-                     backend: str = "pallas", *, interpret: bool = True,
+                     backend: str = "pallas", *,
+                     interpret: Optional[bool] = None,
                      persist: bool = True, meta: Optional[Dict] = None
                      ) -> None:
     """Install coefficient vectors (fit output, tests, offline calib).
@@ -310,7 +314,7 @@ def features_for(spec: ConvSpec, algo, config, *,
 # prediction / ranking
 # --------------------------------------------------------------------------
 def predict_time(spec: ConvSpec, algo, config, *, backend: str = "pallas",
-                 interpret: bool = True, batch: int = 1
+                 interpret: Optional[bool] = None, batch: int = 1
                  ) -> Optional[float]:
     """Predicted wall-clock seconds, or None when unfitted/unpriceable."""
     coefs = coefficients(backend, interpret)
@@ -327,7 +331,7 @@ def predict_time(spec: ConvSpec, algo, config, *, backend: str = "pallas",
 
 
 def rank_candidates(spec: ConvSpec, algo, candidates=None, *,
-                    backend: str = "pallas", interpret: bool = True,
+                    backend: str = "pallas", interpret: Optional[bool] = None,
                     batch: int = 1
                     ) -> Optional[List[Tuple[object, float]]]:
     """Launchable candidates sorted by predicted time (fastest first).
@@ -358,7 +362,7 @@ def rank_candidates(spec: ConvSpec, algo, candidates=None, *,
 
 
 def best_config(spec: ConvSpec, backend: str, algo_name: str,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """Model-predicted best ``KernelConfig`` for one algorithm, or None.
 
     The planner's fallback when the timing cache has no entry — cold
@@ -374,7 +378,7 @@ def best_config(spec: ConvSpec, backend: str, algo_name: str,
 
 
 def select_algorithm(spec: ConvSpec, names: Sequence[str],
-                     backend: str, interpret: bool = True
+                     backend: str, interpret: Optional[bool] = None
                      ) -> Optional[str]:
     """Model-predicted fastest among ``names`` (each at its predicted
     best config), or None when any candidate cannot be priced.
@@ -447,8 +451,9 @@ def _fit_nonneg(X: np.ndarray, y: np.ndarray) -> List[float]:
 
 
 def fit_coefficients(probe_specs: Optional[Sequence[ConvSpec]] = None,
-                     backend: str = "pallas", *, interpret: bool = True,
-                     reps: int = 3, persist: bool = True,
+                     backend: str = "pallas", *,
+                     interpret: Optional[bool] = None, reps: int = 3,
+                     persist: bool = True,
                      log=None) -> Dict:
     """Calibrate the model from a handful of probe runs and install the
     per-datapath coefficients.

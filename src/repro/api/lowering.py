@@ -59,6 +59,7 @@ import jax.numpy as jnp
 
 from repro.api.plan import ConvPlan, PrepCache, PreparedWeights
 from repro.api.spec import ConvSpec
+from repro.runtime import default_interpret
 
 # test/debug escape hatch: `with lowering.disabled(): ...` restores the
 # pre-lowering planner behaviour (stride-2/grouped degrade to direct)
@@ -167,7 +168,7 @@ class CompositePlan:
     kind: str                                 # 'polyphase' | 'grouped'
     sub_plans: Tuple[Any, ...]                # ConvPlan | CompositePlan
     sub_meta: Tuple[Any, ...]                 # polyphase: (a, b, Rk) per sub
-    interpret: bool = True
+    interpret: bool = dataclasses.field(default_factory=default_interpret)
     cost: Optional[float] = None              # comparable to direct estimate
     config: Optional[Any] = None              # uniform override via with_config
     _prep: PrepCache = dataclasses.field(default_factory=PrepCache,

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api.spec import ConvSpec
+from repro.runtime import default_interpret
 from repro.core.conv2d import transform_weights_2d
 from repro.core.generator import BilinearAlgorithm
 import repro.quant.fake_quant as fq
@@ -120,7 +121,8 @@ class ConvPlan:
     backend: str
     algo_name: str                            # registry name or 'direct'
     algorithm: Optional[BilinearAlgorithm]    # None = direct path
-    interpret: bool = True                    # Pallas interpret mode (CPU)
+    # Pallas interpret mode: off on a TPU, on elsewhere (repro.runtime)
+    interpret: bool = dataclasses.field(default_factory=default_interpret)
     cost: Optional[float] = None              # planner's BOPs estimate
     config: Optional[Any] = None              # tuning.KernelConfig (measured)
     _prep: PrepCache = dataclasses.field(

@@ -30,7 +30,8 @@ Algorithm resolution happens in one place, for every call site:
     et al., 2020), so selecting them on BOPs alone would win the cost
     model and lose the model accuracy.
 
-Plans are memoized on (spec, backend, algo, interpret) — specs are frozen
+Plans are memoized on (spec, backend, algo, interpret) — ``interpret``
+resolved from the platform when not given — specs are frozen
 dataclasses, so repeated call sites share one plan and its prepared-weight
 cache.
 """
@@ -43,6 +44,7 @@ from repro.api import registry
 from repro.api.plan import ConvPlan
 from repro.api.spec import ConvSpec
 from repro.quant.bops import ConvWorkload, direct_conv_bops, fastconv_bops
+from repro.runtime import resolve_interpret
 
 _FP_SURROGATE_BITS = 16   # cost-model bit width for unquantized specs
 
@@ -82,7 +84,7 @@ def estimate_cost(spec: ConvSpec, algo_name: str) -> float:
 
 
 def select_algorithm(spec: ConvSpec, backend: Optional[str] = None,
-                     interpret: bool = True) -> str:
+                     interpret: Optional[bool] = None) -> str:
     """Cheapest eligible algorithm for the spec (may be 'direct').
 
     With ``backend`` given, selection walks three tiers of evidence:
@@ -180,7 +182,7 @@ def _plan_cached(spec: ConvSpec, backend: str, algo: str,
 
 
 def plan(spec: ConvSpec, *, backend: str = "reference", algo: str = "auto",
-         interpret: bool = True) -> ConvPlan:
+         interpret: Optional[bool] = None) -> ConvPlan:
     """Resolve a :class:`ConvSpec` into an executable plan.
 
     Returns a :class:`ConvPlan` for native specs, or a
@@ -191,7 +193,7 @@ def plan(spec: ConvSpec, *, backend: str = "reference", algo: str = "auto",
     """
     from repro import faults
     faults.maybe_fault(faults.PLAN, detail=spec)
-    return _plan_cached(spec, backend, algo, interpret)
+    return _plan_cached(spec, backend, algo, resolve_interpret(interpret))
 
 
 def invalidate_plan_cache() -> None:

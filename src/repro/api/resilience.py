@@ -1,18 +1,23 @@
 """Graceful degradation for ``ConvPlan.apply``: fallback chain, circuit
 breakers, and an optional numerical guardrail.
 
-A fused-kernel failure (compile error, VMEM overflow, an interpret/TPU
-mismatch surfacing as a runtime crash) used to propagate straight out of
-``ConvPlan.apply`` — killing every co-batched serving request, and doing
-it again on the next batch because nothing remembered the failure.  This
-module is the plan-tier half of the resilience story:
+A kernel failure at run time (a device error while a kernel executes)
+used to propagate straight out of ``ConvPlan.apply`` — killing every
+co-batched serving request, and doing it again on the next batch because
+nothing remembered the failure.  This module is the plan-tier half of the
+resilience story:
 
-  * **degradation chain** — on exception, the pallas int8 datapath falls
-    fused -> staged -> reference.  fused and staged share one integer
-    grid and are *bit-identical* (``repro.testing.assert_conv_conformance``
-    invariant), so the first fallback level changes nothing a client can
-    observe; the reference int8 simulation is the fp-epsilon-close last
-    resort.  fp pallas plans fall straight to the reference backend.
+  * **degradation chain** — on a runtime failure (:func:`degradable`: an
+    injected fault, a guardrail violation, or an error the device raised
+    while executing), the pallas int8 datapath falls
+    fused -> staged -> reference.  A kernel that cannot be lowered or
+    compiled, and a programming error (``AttributeError``, ``TypeError``,
+    ``NotImplementedError`` ...), propagate out of ``apply``: a fallback
+    would hide a datapath that cannot run on the device at all.  fused
+    and staged share one integer grid and are *bit-identical*
+    (``repro.testing.assert_conv_conformance`` invariant), so the first
+    fallback level changes nothing a client can observe; the reference
+    int8 simulation is the fp-epsilon-close last resort.  fp pallas plans fall straight to the reference backend.
   * **circuit breaker per (spec, backend, level)** — ``failure_threshold``
     consecutive failures open the breaker: the broken level stops being
     *attempted* under traffic (the fallback is pinned, each request pays
@@ -274,6 +279,23 @@ def _emit(kind: str) -> None:
 # ---------------------------------------------------------------------------
 # the degradation chain
 # ---------------------------------------------------------------------------
+def _compile_failure(e: BaseException) -> bool:
+    """Does this runtime error report a lowering or compile failure (Mosaic
+    or XLA) rather than a failure while executing?"""
+    msg = str(e)
+    return "Mosaic" in msg or "compil" in msg.lower()
+
+
+def degradable(e: BaseException) -> bool:
+    """May the chain absorb ``e`` and try the next level?  Only runtime
+    failures: ``RuntimeError`` (injected faults and guardrail violations
+    are ones) that is neither ``NotImplementedError`` nor a lowering or
+    compile failure.  Everything else is a defect in the program."""
+    return isinstance(e, RuntimeError) \
+        and not isinstance(e, NotImplementedError) \
+        and not _compile_failure(e)
+
+
 def engaged(plan) -> bool:
     """Does the chain wrap this plan's apply?  Pallas-backend plans only:
     the reference backend IS the last resort (nothing to fall back to),
@@ -313,11 +335,12 @@ def _levels(plan, prep):
 def apply_resilient(plan, x, prep, *, bias=None):
     """Run ``plan`` through the degradation chain.
 
-    Healthy path: one breaker lookup, one try, zero copies.  On failure
-    (exception or guardrail violation) the level's breaker records it and
-    the next level runs; open breakers are skipped without attempting.
-    Raises the last error when every level fails, or
-    :class:`BreakerOpenError` when every level was breaker-skipped.
+    Healthy path: one breaker lookup, one try, zero copies.  On a
+    :func:`degradable` failure the level's breaker records it and the next
+    level runs; open breakers are skipped without attempting.  Any other
+    exception propagates at once.  Raises the last error when every level
+    fails, or :class:`BreakerOpenError` when every level was
+    breaker-skipped.
     """
     from repro.api import backends
     pol = _POLICY
@@ -337,8 +360,10 @@ def apply_resilient(plan, x, prep, *, bias=None):
                 violation = pol.guardrail.check(lp, x, prep, y)
                 if violation is not None:
                     raise GuardrailViolation(f"{level}: {violation}")
-        except Exception as e:               # noqa: BLE001 — the chain IS
-            last_err = e                     # the handler of last resort
+        except RuntimeError as e:
+            if not degradable(e):
+                raise
+            last_err = e
             _emit("resilience_apply_failure")
             if isinstance(e, GuardrailViolation):
                 _emit("resilience_guardrail_trip")

@@ -75,7 +75,7 @@ class ServingCache:
         self._hits = self._misses = self._prepares = self._evictions = 0
 
     def get(self, spec: ConvSpec, w, *, backend: str = "reference",
-            algo: str = "auto", interpret: bool = True,
+            algo: str = "auto", interpret: Optional[bool] = None,
             act_scale=None, w_scale=None,
             key: Optional[Any] = None) -> Tuple[ConvPlan, PreparedWeights]:
         """Resolve ``spec`` and return its cached (plan, prepared weights).
@@ -90,7 +90,9 @@ class ServingCache:
         """
         from repro import faults
         from repro.api import planner
+        from repro.runtime import resolve_interpret
         faults.maybe_fault(faults.CACHE, detail=spec)
+        interpret = resolve_interpret(interpret)
         p = planner.plan(spec, backend=backend, algo=algo,
                          interpret=interpret)
         operands = (w, act_scale, w_scale)

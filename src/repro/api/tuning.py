@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api.spec import ConvSpec
+from repro.runtime import resolve_interpret
 
 _ENV_CACHE = "REPRO_TUNING_CACHE"
 _DEFAULT_CACHE = os.path.join(os.path.expanduser("~"), ".cache", "repro",
@@ -46,7 +47,7 @@ class KernelConfig:
     """One executable configuration of the pallas int8 datapath."""
 
     datapath: str = "fused"       # 'fused' | 'staged'
-    tile_block: int = 8           # staged transform/inverse tile block
+    tile_block: int = 32          # staged transform/inverse tile block
     chan_block: int = 128         # staged transform/inverse channel block
     k_block: Optional[int] = 128  # C_in reduction block (None = full K)
     cout_block: int = 128         # fused C_out block
@@ -172,12 +173,14 @@ def _save() -> None:
         _write(cache_path(), _snapshot_locked())
 
 
-def spec_key(spec: ConvSpec, backend: str, interpret: bool = True) -> str:
+def spec_key(spec: ConvSpec, backend: str,
+             interpret: Optional[bool] = None) -> str:
     """Stable cache key: (workload, backend, device, interpret mode).
 
     ``interpret`` is part of the key — interpret-mode (CPU emulation)
     timings rank completely differently from compiled TPU kernels and
-    must never govern non-interpret plans.
+    must never govern non-interpret plans; ``None`` follows the platform
+    (:func:`repro.runtime.resolve_interpret`).
 
     New spec fields append tokens only at their NON-default values
     (``g{groups}`` for grouped, ``dw`` for 2-D depthwise) — the same
@@ -196,11 +199,11 @@ def spec_key(spec: ConvSpec, backend: str, interpret: bool = True) -> str:
     return (f"r{spec.rank}k{spec.kernel_size}s{spec.stride}"
             f"p{spec.padding}ci{spec.in_channels}co{spec.out_channels}"
             f"sp{spec.spatial}q{qk}{extra}|{backend}|{jax.default_backend()}"
-            f"|i{int(interpret)}")
+            f"|i{int(resolve_interpret(interpret))}")
 
 
 def lookup(spec: ConvSpec, backend: str,
-           interpret: bool = True) -> Dict[str, Dict]:
+           interpret: Optional[bool] = None) -> Dict[str, Dict]:
     """Measured entries for (spec, backend): {algo_name: {time_s, config}}.
 
     Empty dict when nothing has been measured — the planner then falls
@@ -210,7 +213,7 @@ def lookup(spec: ConvSpec, backend: str,
 
 
 def get_config(spec: ConvSpec, backend: str, algo_name: str,
-               interpret: bool = True) -> Optional[KernelConfig]:
+               interpret: Optional[bool] = None) -> Optional[KernelConfig]:
     """Best measured kernel config for one algorithm, or None."""
     entry = _load().get(spec_key(spec, backend, interpret),
                         {}).get(algo_name)
@@ -222,7 +225,7 @@ def get_config(spec: ConvSpec, backend: str, algo_name: str,
 def record(spec: ConvSpec, backend: str, algo_name: str, time_s: float,
            config: Optional[KernelConfig] = None, *,
            predicted_s: Optional[float] = None,
-           interpret: bool = True, persist: bool = True) -> None:
+           interpret: Optional[bool] = None, persist: bool = True) -> None:
     """Store one measurement (used by autotune; exposed for tests/offline
     calibration imports).  Last measurement wins — a re-tune must be able
     to correct entries that no longer reproduce (driver/library upgrades,
@@ -334,7 +337,7 @@ def autotune(spec: ConvSpec, backend: str = "pallas", *,
              candidates: Sequence[KernelConfig] = DEFAULT_CANDIDATES,
              include_direct: bool = True, reps: int = 3,
              top_k: Optional[int] = 3,
-             interpret: bool = True, persist: bool = True,
+             interpret: Optional[bool] = None, persist: bool = True,
              log=None) -> Dict[str, Dict]:
     """Measure candidate configs for ``spec`` and persist the winners.
 

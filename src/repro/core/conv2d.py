@@ -12,8 +12,11 @@ path and the oracle for those kernels.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Callable, Optional, Tuple
+import math
+from fractions import Fraction
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +47,122 @@ def transform_matrices(algo: BilinearAlgorithm, dtype: str = "float32"
     with jax.ensure_compile_time_eval():
         return (jnp.asarray(algo.bt(), dt), jnp.asarray(algo.g(), dt),
                 jnp.asarray(algo.at(), dt))
+
+
+# --------------------------------------------------------------------------
+# Static-coefficient transforms: the one arithmetic every int8 path shares
+# --------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class StaticTransform:
+    """A transform matrix as integer rows over one common denominator:
+    ``mat = rows / den``.  Static and hashable, so kernels unroll it into
+    adds with the coefficients baked in."""
+
+    rows: Tuple[Tuple[int, ...], ...]
+    den: int = 1
+
+    @classmethod
+    def of(cls, mat) -> "StaticTransform":
+        den = math.lcm(*(Fraction(c).denominator for row in mat for c in row))
+        return cls(tuple(tuple(int(Fraction(c) * den) for c in row)
+                         for row in mat), den)
+
+
+@functools.lru_cache(maxsize=None)
+def transform_coefficients(algo: BilinearAlgorithm
+                           ) -> Tuple[StaticTransform, StaticTransform]:
+    """``(B^T, A^T)`` of ``algo`` as :class:`StaticTransform` (SFC's B^T is
+    all 0/+-1 — additions only; its A^T is 1/N times an integer matrix)."""
+    return StaticTransform.of(algo.BT), StaticTransform.of(algo.AT)
+
+
+def _multiple(c: int, v: jnp.ndarray) -> jnp.ndarray:
+    """``c * v`` for an integer ``c > 0`` from exact power-of-two scalings
+    and adds: no rounded product ever feeds an add, so a compiler that
+    contracts multiply-add into FMA cannot change the result."""
+    acc, bit = None, 0
+    while c:
+        if c & 1:
+            term = v if bit == 0 else v * float(1 << bit)
+            acc = term if acc is None else acc + term
+        c >>= 1
+        bit += 1
+    return acc
+
+
+def combine(row: Tuple[int, ...], term: Callable[[int], jnp.ndarray]
+            ) -> jnp.ndarray:
+    """``sum_j row[j] * term(j)`` for integer ``row``, in ascending ``j``:
+    zero coefficients are skipped, +-1 become an add or a subtract.
+
+    Every datapath (fused and staged kernels, the reference int8
+    simulation) evaluates its transforms through this one function, so
+    they round identically and quantize onto the same integer grid.
+    """
+    acc = None
+    for j, c in enumerate(row):
+        if c == 0:
+            continue
+        v = _multiple(abs(c), term(j))
+        if acc is None:
+            acc = v if c > 0 else -v
+        else:
+            acc = acc + v if c > 0 else acc - v
+    if acc is None:
+        raise ValueError(f"all-zero transform row {row}")
+    return acc
+
+
+def separable_2d(mat: StaticTransform,
+                 load_col: Callable[[int], jnp.ndarray],
+                 emit_col: Callable[[int, List[jnp.ndarray]], None]
+                 ) -> None:
+    """Evaluate ``Y = mat @ X @ mat^T`` one output column at a time.
+
+    ``load_col(j)`` returns input column ``X[:, j]`` stacked on a leading
+    axis, ``(n_in, ...)`` — typically lane-dense ``(columns, channels)``
+    slabs, one per tile row.  ``emit_col(b, ys)`` receives output column
+    ``b`` as the list ``ys[a] = Y[a, b]``.  Each input column is read
+    once; the column transform combines whole columns, the row transform
+    combines their leading-axis slices.  A denominator is applied once,
+    as the last operation before ``emit_col``.
+    """
+    rows = mat.rows
+    n_in = len(rows[0])
+    norm = 1.0 / float(mat.den * mat.den)
+    cols = {}
+
+    def col_in(j):
+        if j not in cols:
+            cols[j] = load_col(j)
+        return cols[j]
+
+    for b in range(len(rows)):
+        xb = combine(rows[b], col_in)                 # (n_in, ...)
+        parts = [xb[i] for i in range(n_in)]
+        ys = [combine(row, parts.__getitem__) for row in rows]
+        emit_col(b, ys if mat.den == 1 else [y * norm for y in ys])
+
+
+def quantize_slab(v: jnp.ndarray, inv_scale, qmax: int) -> jnp.ndarray:
+    """Static per-frequency quantization onto the integer grid (values stay
+    float).  Multiplies by a reciprocal computed once per call
+    (:func:`reciprocal_scale`), which rounds the same way on every device."""
+    return jnp.clip(jnp.round(v * inv_scale), -qmax, qmax)
+
+
+def reciprocal_scale(act_scale: jnp.ndarray) -> jnp.ndarray:
+    """``1 / act_scale`` in f32: the multiplier :func:`quantize_slab` takes."""
+    return 1.0 / jnp.asarray(act_scale, jnp.float32)
+
+
+def dequant_scale(act_scale: jnp.ndarray, w_scale: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """``(P, Cout)`` combined dequantization scale ``s_x[p] * s_w[p, o]``
+    from act_scale ``(t, t)`` and w_scale ``(t, t, Cout)``."""
+    P = act_scale.shape[0] * act_scale.shape[1]
+    return (jnp.asarray(act_scale, jnp.float32).reshape(P, 1)
+            * jnp.asarray(w_scale, jnp.float32).reshape(P, -1))
 
 
 # --------------------------------------------------------------------------
@@ -83,14 +202,26 @@ def transform_input_2d(x: jnp.ndarray, algo: BilinearAlgorithm,
     xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
     nH = (xp.shape[1] - (R - 1)) // M
     nW = (xp.shape[2] - (R - 1)) // M
-    idx_h = _overlap_tiles_1d(nH, M, L)
-    idx_w = _overlap_tiles_1d(nW, M, L)
-    tiles = xp[:, idx_h, :, :]            # (B, nH, L, Wp, C)
-    tiles = tiles[:, :, :, idx_w, :]      # (B, nH, L, nW, L, C)
-    tiles = jnp.transpose(tiles, (0, 1, 3, 2, 4, 5))  # (B,nH,nW,L,L,C)
-    bt = transform_matrices(algo, x.dtype.name)[0]
-    tx = jnp.einsum("ti,bnwijc,uj->bnwtuc", bt, tiles, bt)
-    return tx, (out_h, out_w, nH, nW)
+    return _input_transform(xp, algo, nH, nW), (out_h, out_w, nH, nW)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _input_transform(xp: jnp.ndarray, algo: BilinearAlgorithm, nH: int,
+                     nW: int) -> jnp.ndarray:
+    """Padded (B, Hp, Wp, C) -> (B, nH, nW, t, t, C) through
+    :func:`separable_2d`: element (i, j) of every tile is one stride-M
+    slice of ``xp``."""
+    M = algo.M
+    bt = transform_coefficients(algo)[0]
+    out = []
+
+    def load_col(j):
+        return jnp.stack([xp[:, i:i + M * (nH - 1) + 1:M,
+                             j:j + M * (nW - 1) + 1:M, :]
+                          for i in range(algo.L)])
+
+    separable_2d(bt, load_col, lambda b, ys: out.append(jnp.stack(ys, -2)))
+    return jnp.stack(out, axis=-2)                # (B, nH, nW, t_a, t_b, C)
 
 
 def transform_weights_2d(w: jnp.ndarray, algo: BilinearAlgorithm) -> jnp.ndarray:
@@ -114,7 +245,8 @@ def inverse_transform_2d(ty: jnp.ndarray, algo: BilinearAlgorithm,
     """(B,nH,nW,t,t,Cout) -> (B,H_out,W_out,Cout)."""
     out_h, out_w, nH, nW = geom
     at = transform_matrices(algo, ty.dtype.name)[2]
-    y = jnp.einsum("mt,bnwtuo,pu->bnwmpo", at, ty, at)  # (B,nH,nW,M,M,O)
+    y = jnp.einsum("mt,bnwtuo,pu->bnwmpo", at, ty, at,
+                   precision=jax.lax.Precision.HIGHEST)  # (B,nH,nW,M,M,O)
     B = y.shape[0]
     O = y.shape[-1]
     M = algo.M

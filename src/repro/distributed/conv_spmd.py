@@ -56,7 +56,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.sharding import batch_axes, sanitize_pspec
@@ -194,8 +193,8 @@ class SpmdPallasBackend:
                                  act_scale=ops.get("act_scale"))
             return inner.apply(plan, ops["x"], lp, bias=ops.get("bias"))
 
-        # check_rep=False: pallas_call is opaque to shard_map's replication
+        # check_vma=False: pallas_call is opaque to shard_map's replication
         # checker; replication of the dropped (non-divisible) axes is
         # guaranteed by construction — every shard sees identical operands.
-        return shard_map(_local, mesh=mesh, in_specs=(specs,),
-                         out_specs=out_spec, check_rep=False)(operands)
+        return jax.shard_map(_local, mesh=mesh, in_specs=(specs,),
+                             out_specs=out_spec, check_vma=False)(operands)

@@ -23,7 +23,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -54,10 +53,10 @@ def pipeline_apply(stage_fn: Callable, params_staged, x: jnp.ndarray,
         lambda a: P(axis, *([None] * (a.ndim - 1))), params_staged)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, P(*([None] * (x_micro.ndim)))),
         out_specs=P(*([None] * x_micro.ndim)),
-        check_rep=False)
+        check_vma=False)
     def run(params_local, xm):
         stage = jax.lax.axis_index(axis)
         sp = jax.tree_util.tree_map(lambda a: a[0], params_local)

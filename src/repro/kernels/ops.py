@@ -8,8 +8,11 @@
        -> untile
 
 Scales are static (PTQ-calibrated): act_scale (t, t), w_scale (t, t, Cout).
-On this CPU-only container the kernels run with interpret=True; on TPU pass
-interpret=False (the layouts/BlockSpecs are chosen for v5e).
+Layouts keep the tile index on sublanes and channels on lanes: tiles
+(L, L, nT, C) -> transform (t, t, nT, C) = X (P, nT, C) for the matmul ->
+inverse (M, M, nT, O).  ``interpret=None`` compiles the kernels on a TPU
+and runs them in the Pallas interpreter elsewhere
+(:func:`repro.runtime.resolve_interpret`).
 """
 from __future__ import annotations
 
@@ -18,7 +21,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import conv2d as c2d
 from repro.core.generator import BilinearAlgorithm
@@ -29,7 +31,10 @@ from repro.kernels.sfc_inverse import sfc_inverse
 
 def extract_tiles(x: jnp.ndarray, algo: BilinearAlgorithm,
                   padding: str = "SAME") -> Tuple[jnp.ndarray, Tuple]:
-    """(B,H,W,C) -> flat tiles (B*nH*nW, L, L, C) + geometry."""
+    """(B,H,W,C) -> tiles (L, L, B*nH*nW, C) + geometry.
+
+    Tile element (i, j) of every tile is one stride-M slice of the padded
+    input, so the gather is L^2 strided slices (no index arrays)."""
     B, H, W, C = x.shape
     M, R, L = algo.M, algo.R, algo.L
     lo_h, hi_h, out_h = c2d.pad_amounts(H, M, R, padding)
@@ -37,23 +42,20 @@ def extract_tiles(x: jnp.ndarray, algo: BilinearAlgorithm,
     xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
     nH = (xp.shape[1] - (R - 1)) // M
     nW = (xp.shape[2] - (R - 1)) // M
-    # single gather directly into (B, nH, nW, L, L, C) — the chained
-    # xp[:, ih][:, :, :, iw] form materialized an extra (B, nH, L, Wp, C)
-    # intermediate and needed a transpose afterwards
-    ih = np.arange(nH)[:, None] * M + np.arange(L)[None, :]   # (nH, L)
-    iw = np.arange(nW)[:, None] * M + np.arange(L)[None, :]   # (nW, L)
-    tiles = xp[:, ih[:, None, :, None], iw[None, :, None, :], :]
-    tiles = tiles.reshape(B * nH * nW, L, L, C)
+    tiles = jnp.stack([jnp.stack(
+        [xp[:, i:i + M * (nH - 1) + 1:M, j:j + M * (nW - 1) + 1:M, :]
+         .reshape(B * nH * nW, C) for j in range(L)]) for i in range(L)])
     return tiles, (B, out_h, out_w, nH, nW)
 
 
 def untile(y_tiles: jnp.ndarray, algo: BilinearAlgorithm,
            geom: Tuple) -> jnp.ndarray:
+    """(M, M, B*nH*nW, O) -> (B, H', W', O)."""
     B, out_h, out_w, nH, nW = geom
     M = algo.M
     O = y_tiles.shape[-1]
-    y = y_tiles.reshape(B, nH, nW, M, M, O)
-    y = jnp.transpose(y, (0, 1, 3, 2, 4, 5)).reshape(B, nH * M, nW * M, O)
+    y = y_tiles.reshape(M, M, B, nH, nW, O)
+    y = jnp.transpose(y, (2, 3, 0, 4, 1, 5)).reshape(B, nH * M, nW * M, O)
     return y[:, :out_h, :out_w, :]
 
 
@@ -72,9 +74,9 @@ def quantized_fastconv2d(x: jnp.ndarray, wq: jnp.ndarray,
                          act_scale: jnp.ndarray, w_scale: jnp.ndarray,
                          algo: BilinearAlgorithm, *,
                          padding: str = "SAME", bits: int = 8,
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          k_block: Optional[int] = None,
-                         tile_block: int = 8,
+                         tile_block: int = 32,
                          chan_block: int = 128) -> jnp.ndarray:
     """int8 SFC convolution with pre-quantized weights (staged pipeline).
 
@@ -86,20 +88,15 @@ def quantized_fastconv2d(x: jnp.ndarray, wq: jnp.ndarray,
     transform/inverse stages.
     """
     t = algo.t
-    bt, _, at = c2d.transform_matrices(algo, "float32")
     tiles, geom = extract_tiles(x, algo, padding)
-    xq = sfc_transform_quantize(tiles, bt, act_scale, bits=bits,
+    xq = sfc_transform_quantize(tiles, algo, act_scale, bits=bits,
                                 interpret=interpret, tile_block=tile_block,
                                 chan_block=chan_block)
-    T = xq.shape[0]
-    C = xq.shape[-1]
-    X = jnp.transpose(xq.reshape(T, t * t, C), (1, 0, 2))   # (P, T, C)
-    Y = tdmm_int8(X, wq, act_scale.reshape(t * t),
+    T, C = xq.shape[2], xq.shape[3]
+    Y = tdmm_int8(xq.reshape(t * t, T, C), wq, act_scale.reshape(t * t),
                   w_scale.reshape(t * t, -1), interpret=interpret,
                   k_block=k_block)
-    O = Y.shape[-1]
-    ty = jnp.transpose(Y, (1, 0, 2)).reshape(T, t, t, O)
-    y_tiles = sfc_inverse(ty, at, interpret=interpret,
+    y_tiles = sfc_inverse(Y.reshape(t, t, T, -1), algo, interpret=interpret,
                           tile_block=tile_block, chan_block=chan_block)
     return untile(y_tiles, algo, geom)
 
@@ -112,8 +109,8 @@ def quantized_fastconv2d_depthwise(x: jnp.ndarray, wq: jnp.ndarray,
                                    w_scale: jnp.ndarray,
                                    algo: BilinearAlgorithm, *,
                                    padding: str = "SAME", bits: int = 8,
-                                   interpret: bool = True,
-                                   tile_block: int = 8,
+                                   interpret: Optional[bool] = None,
+                                   tile_block: int = 32,
                                    chan_block: int = 128) -> jnp.ndarray:
     """int8 depthwise SFC convolution (staged pipeline).
 
@@ -124,33 +121,28 @@ def quantized_fastconv2d_depthwise(x: jnp.ndarray, wq: jnp.ndarray,
     there is no channel contraction, so no k-blocking either.
     """
     t = algo.t
-    bt, _, at = c2d.transform_matrices(algo, "float32")
     tiles, geom = extract_tiles(x, algo, padding)
-    xq = sfc_transform_quantize(tiles, bt, act_scale, bits=bits,
+    xq = sfc_transform_quantize(tiles, algo, act_scale, bits=bits,
                                 interpret=interpret, tile_block=tile_block,
                                 chan_block=chan_block)
-    T = xq.shape[0]
-    C = xq.shape[-1]
-    X = jnp.transpose(xq.reshape(T, t * t, C), (1, 0, 2))   # (P, T, C)
-    Y = tdmm_int8_depthwise(X, wq.reshape(t * t, C),
+    T, C = xq.shape[2], xq.shape[3]
+    Y = tdmm_int8_depthwise(xq.reshape(t * t, T, C), wq.reshape(t * t, C),
                             act_scale.reshape(t * t),
                             w_scale.reshape(t * t, C), interpret=interpret)
-    ty = jnp.transpose(Y, (1, 0, 2)).reshape(T, t, t, C)
-    y_tiles = sfc_inverse(ty, at, interpret=interpret,
+    y_tiles = sfc_inverse(Y.reshape(t, t, T, C), algo, interpret=interpret,
                           tile_block=tile_block, chan_block=chan_block)
     return untile(y_tiles, algo, geom)
 
 
 @functools.partial(jax.jit, static_argnames=("algo", "padding", "interpret"))
 def fastconv2d_fp(x: jnp.ndarray, w: jnp.ndarray, algo: BilinearAlgorithm, *,
-                  padding: str = "SAME", interpret: bool = True
+                  padding: str = "SAME", interpret: Optional[bool] = None
                   ) -> jnp.ndarray:
     """Unquantized kernel path (transform -> f32 tdmm -> inverse)."""
-    bt, _, at = c2d.transform_matrices(algo, x.dtype.name)
-    t = algo.t
     tiles, geom = extract_tiles(x, algo, padding)
-    tx = sfc_transform(tiles, bt, interpret=interpret)
+    tx = sfc_transform(tiles, algo, interpret=interpret)
     tw = c2d.transform_weights_2d(w, algo)
-    ty = jnp.einsum("ntuc,tuco->ntuo", tx, tw)
-    y_tiles = sfc_inverse(ty, at, interpret=interpret)
+    ty = jnp.einsum("tunc,tuco->tuno", tx, tw,
+                    precision=jax.lax.Precision.HIGHEST)
+    y_tiles = sfc_inverse(ty, algo, interpret=interpret)
     return untile(y_tiles, algo, geom)

@@ -11,28 +11,26 @@ This kernel keeps the whole pipeline on-chip (EXPERIMENTS.md §Perf):
   k innermost
 
 Per grid step it
-  * reads one overlapping (imgs, span, W_padded, k_block) input strip
+  * DMAs one overlapping (imgs, span, W_padded, k_block) input strip
     group — ``rows`` consecutive tile-rows (span = (rows-1)*M + L) of
-    ``imgs`` images — straight from HBM, either via an Unblocked BlockSpec
-    index map at row stride rows*M, or (``double_buffer``) via a manual
-    ``pltpu.make_async_copy`` DMA into a two-slot VMEM scratch so the next
-    strip's HBM read overlaps the current strip's transform + matmul;
-  * applies the additions-only B^T X B transform per tile column and the
-    fused per-frequency intN quantization in VMEM/registers; the quantized
-    int8 strips are cached in a VMEM scratch across C_out blocks (bounded
+    ``imgs`` images — from HBM into a VMEM landing buffer.  The copy is
+    synchronous by default; ``double_buffer`` adds a second slot and
+    prefetches the next strip group while this one is computed;
+  * applies the additions-only B^T X B transform and the per-frequency
+    intN quantization with :func:`repro.core.conv2d.separable_2d` — the
+    transform arithmetic the staged kernels and the reference simulation
+    share — on lane-dense (nW, k_block) slabs read at stride M, and
+    stages the quantized strips as the (P, imgs*rows*nW, k_block) matmul
+    LHS.  The int8 strips are cached in VMEM across C_out blocks (bounded
     by ``XQ_CACHE_BYTES``; recomputed per block when they do not fit), so
-    the transform runs once per (strip group, k-block), not once per
-    output block;
-  * runs the t^2-position int8 MXU matmuls against the matching weight
-    k-block — the LHS stacks all imgs*rows*nW tile columns of the group,
-    so small images (nW*M = 7..14) still feed the 128-lane MXU a full
-    batch of rows instead of a sliver — and accumulates into an int32
-    VMEM scratch that persists across the C_in k-blocks, so full-K VMEM
-    residency (which caps the staged ``tdmm_int8`` near C_in ~ 2048) is
-    never required;
+    the transform runs once per (strip group, k-block);
+  * runs the t^2 int8 x int8 -> int32 MXU matmuls, one 2-D dot per
+    frequency, against the matching weight k-block — the LHS stacks all
+    imgs*rows*nW tile columns of the group — and accumulates into an
+    int32 VMEM scratch that persists across the C_in k-blocks;
   * on the last k-block dequantizes with the static per-frequency scales
-    and applies the correction-term inverse A^T Y A, writing one spatial
-    (imgs, rows*M, nW*M) output strip group.
+    and applies the correction-term inverse A^T Y A (same shared helper),
+    storing the (imgs, rows*M, nW*M) output strip group at stride M.
 
 The transform-domain tensor therefore never touches HBM.
 
@@ -43,26 +41,31 @@ divisor of B so no padded images are computed).  ``rows_per_step=None``
 resolves via :func:`auto_rows_per_step`, the largest candidate whose
 per-step footprint (:func:`fused_vmem_bytes`, the budget math below) fits
 ``VMEM_LIMIT_BYTES``.  All groupings are bit-identical to
-``rows_per_step=1``: the per-strip transform arithmetic and the per-column
-matmul contraction are unchanged, only the grid batching differs.
+``rows_per_step=1``: the per-strip arithmetic and the per-column matmul
+contraction are unchanged, only the grid batching differs.
 
 VMEM budget per grid step (f32 in, defaults K_BLOCK=COUT_BLOCK=128, the
 VGG-16 224x224 worst case with SFC-6(7x7,3x3): L=9, t=12, nW=32, Wp=226,
-rows=1):
+rows=1, C_in=512 so n_k=4 with the xq cache):
   input strip : 9 * 226 * 128 * 4B          = 1.0 MiB   (x2 double_buffer)
-  row xform   : 12 * 226 * 128 * 4B         = 1.4 MiB
-  xq cache    : <= XQ_CACHE_BYTES           = 4.0 MiB
-  weights     : 144 * 128 * 128 * 1B        = 2.3 MiB
+  staged LHS  : 144 * 32 * 128 * 4B         = 2.3 MiB   (f32 quantized)
+  xq cache    : 4 * 144 * 32 * 128 * 1B     = 2.3 MiB   (<= XQ_CACHE_BYTES)
+  weights     : 2 * 144 * 128 * 128 * 1B    = 4.5 MiB   (pipelined, x2)
+  scales      : 2 * 144 * 128 * 4B          = 0.1 MiB   (pipelined, x2)
   int32 acc   : 144 * 32 * 128 * 4B         = 2.3 MiB
-  out strip   : 7 * 224 * 128 * 4B          = 0.8 MiB    (~12 MiB < 16 MiB)
+  out strip   : 2 * 7 * 224 * 128 * 4B      = 1.5 MiB   (pipelined, x2)
+                                             ~ 13.9 MiB < 24 MiB
 :func:`fused_vmem_bytes` reproduces exactly these terms (scaled by the
-grouping) and is regression-tested against them.
+grouping) and is regression-tested against them.  The launch asks the
+compiler for ``VMEM_LIMIT_BYTES + VMEM_HEADROOM_BYTES`` of scoped VMEM:
+the headroom holds Mosaic's own scratch (per-frequency dot results,
+spilled transform slabs), which the budget does not count.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,16 +74,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import conv2d as c2d
 from repro.core.generator import BilinearAlgorithm
+from repro.runtime import resolve_interpret
 
 K_BLOCK = 128
 COUT_BLOCK = 128
+# the chip's f32 vreg tiling: a block's last dim is a multiple of LANES
+# (or the whole axis), its second-to-last a multiple of SUBLANES
+LANES = 128
+SUBLANES = 8
 # cap on the quantized-strip cache that amortizes the input transform
 # across C_out blocks (full-K int8 residency of ONE strip group)
 XQ_CACHE_BYTES = 4 * 1024 * 1024
-# per-step VMEM ceiling the batching helper packs against (v5e: 16 MiB
-# usable VMEM per core; the budget math is documented in the module
-# docstring and regression-tested in tests/test_conformance.py)
-VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+# per-step VMEM ceiling the batching helper packs against (the budget
+# math is documented in the module docstring and regression-tested in
+# tests/test_conformance.py); the launch grants this plus the headroom,
+# 32 MiB in all (a v5e TensorCore has 128 MiB of VMEM; the compiler's
+# default scoped grant is 16 MiB, so the grant is passed explicitly)
+VMEM_LIMIT_BYTES = 24 * 1024 * 1024
+VMEM_HEADROOM_BYTES = 8 * 1024 * 1024
 # candidate group sizes auto_rows_per_step tries, largest first
 AUTO_ROWS_CANDIDATES = (8, 4, 2, 1)
 
@@ -125,13 +136,14 @@ def _vmem_bytes(t: int, M: int, L: int, n_w: int, w_padded: int,
     strip = imgs * span * w_padded * kb * 4
     if double_buffer:
         strip *= 2
-    row_xform = t * w_padded * kb * 4      # one strip at a time
-    xq = P * cols * kb                     # int8
-    xq_cache = n_k * P * cols * kb if cache_xq else 0
-    weights = P * kb * cb                  # int8
+    stage = P * cols * kb * 4              # f32 quantized matmul LHS
+    xq_cache = n_k * P * cols * kb if cache_xq else 0   # int8
+    # BlockSpec operands are double-buffered by the Pallas pipeline
+    weights = 2 * P * kb * cb              # int8
+    scales = 2 * P * cb * 4
     acc = P * cols * cb * 4                # int32
-    out = imgs * rows * M * n_w * M * cb * 4
-    return strip + row_xform + xq + xq_cache + weights + acc + out
+    out = 2 * imgs * rows * M * n_w * M * cb * 4
+    return strip + stage + xq_cache + weights + scales + acc + out
 
 
 def fused_vmem_bytes(algo: BilinearAlgorithm, n_w: int, w_padded: int,
@@ -142,9 +154,9 @@ def fused_vmem_bytes(algo: BilinearAlgorithm, n_w: int, w_padded: int,
 
     Reproduces the module docstring's budget table term by term, scaled
     by the (imgs, rows) grouping: input strip group (doubled when
-    double-buffered), the per-strip row-transform intermediate, the int8
-    quantized-strip matmul LHS, the optional full-K xq cache, the weight
-    k-block, the int32 accumulator, and the output strip group.
+    double-buffered), the f32 staged quantized-strip matmul LHS, the
+    optional full-K int8 xq cache, the pipelined weight and scale blocks,
+    the int32 accumulator, and the pipelined output strip group.
     """
     return _vmem_bytes(algo.t, algo.M, algo.L, n_w, w_padded, kb, cb,
                        n_k=n_k, rows=rows, imgs=imgs, cache_xq=cache_xq,
@@ -183,9 +195,11 @@ class FusedGeometry:
     silently diverge from) the kernel's own arithmetic.
 
     Shapes are post-padding: ``x_rows``/``w_padded`` are the padded input
-    extents the strip index maps read against, ``Cp``/``Op`` the padded
-    channel extents.  ``rows_per_step`` is the *resolved* grouping (never
-    None).  For depthwise launches ``n_k == 1``, ``kb == cb`` (the shared
+    extents the strip DMA reads against (``w_padded`` rounded up to the
+    sublane tiling), ``Cp``/``Op`` the padded channel extents (input
+    channel blocks are whole multiples of the 128-lane width).
+    ``rows_per_step`` is the *resolved* grouping (never None).  For
+    depthwise launches ``n_k == 1``, ``kb == cb`` (the shared
     channel block), and ``cache_xq``/``double_buffer`` are forced off —
     there is no reduction to block and no cross-block strip reuse.
     """
@@ -258,16 +272,20 @@ class FusedGeometry:
                            imgs=self.imgs, cache_xq=self.cache_xq,
                            double_buffer=self.double_buffer)
 
-    # ---- strip reads (the Unblocked index map / manual DMA source) ----
+    # ---- strip reads (the DMA source and landing buffer) ----
     @property
     def strip_shape(self) -> Tuple[int, int, int, int]:
         return (self.imgs, self.span, self.w_padded, self.kb)
 
+    @property
+    def strip_slots(self) -> int:
+        """Landing-buffer slots: two when double-buffered, else one."""
+        return self.db_slots if self.double_buffer else 1
+
     def strip_offset(self, i: int, k: int = 0
                      ) -> Tuple[int, int, int, int]:
         """Element offsets of grid step (i, ·, k)'s input strip group —
-        the same arithmetic as the kernel's Unblocked index map and its
-        manual-DMA ``_coords`` helper."""
+        the source window of the kernel's strip DMA."""
         return ((i // self.g_h) * self.imgs,
                 (i % self.g_h) * self.rows * self.M, 0, k * self.kb)
 
@@ -283,10 +301,6 @@ class FusedGeometry:
         only the last one writes the block."""
         del k
         return (i // self.g_h, i % self.g_h, 0, j)
-
-    def db_slot(self, s_idx: int) -> int:
-        """DMA landing slot of strip-sequence entry ``s_idx``."""
-        return s_idx % self.db_slots
 
     # ---- workload accounting (the analytic cost model's inputs) ----
     # These accessors are the ONE place the kernel's per-launch work is
@@ -360,12 +374,11 @@ class FusedGeometry:
             mxu = self.grid0 * self.n_o * self.n_k * self.P * cols \
                 * self.kb * self.cb
             ew = 0
-        # per consuming step: row transform (t x L against the full strip
-        # width), per-column col transform, per-frequency quantize
-        per_step = self.imgs * self.rows * self.kb * (
-            self.t * self.L * self.w_padded
-            + self.nW * self.t * self.t * self.L
-            + self.nW * self.P)
+        # per strip of a consuming step: the column transform (t outputs
+        # of L terms for each of L rows), the row transform (t^2 outputs
+        # of L terms) and the per-frequency quantize, on (nW, kb) slabs
+        per_step = self.imgs * self.rows * self.kb * self.nW * (
+            self.L * self.t * self.L + self.t * self.t * self.L + self.P)
         transform = self.transform_invocations * per_step
         # per finalize: dequant scale (P x cols) + the two inverse einsums
         inverse = self.grid0 * self.n_o * cols * self.cb * (
@@ -376,15 +389,16 @@ class FusedGeometry:
     def scratch_shapes(self) -> Tuple[Tuple[str, Tuple[int, ...], str], ...]:
         """(name, shape, dtype) of every VMEM scratch the launch allocates,
         in ``pallas_call`` order."""
-        out = []
-        if not self.depthwise:
-            out.append(("acc", (self.P, self.cols, self.cb), "int32"))
+        if self.depthwise:
+            out = [("y", (self.t, self.t, self.nW, self.cb), "float32")]
+        else:
+            out = [("acc", (self.P, self.cols, self.cb), "int32"),
+                   ("stage", (self.P, self.cols, self.kb), "float32")]
         if self.cache_xq:
             out.append(("xq_cache", (self.n_k, self.P, self.cols, self.kb),
                         "int8"))
-        if self.double_buffer:
-            out.append(("db_buf", (self.db_slots, self.imgs, self.span,
-                                   self.w_padded, self.kb), "float32"))
+        out.append(("strip_buf", (self.strip_slots,) + self.strip_shape,
+                    "float32"))
         return tuple(out)
 
 
@@ -406,11 +420,13 @@ def fused_geometry(algo: BilinearAlgorithm, B: int, H: int, W: int,
     lo_h, hi_h, out_h = c2d.pad_amounts(H, M, R, padding)
     lo_w, hi_w, out_w = c2d.pad_amounts(W, M, R, padding)
     xp_h = H + lo_h + hi_h
-    Wp = W + lo_w + hi_w
     nH = (xp_h - (R - 1)) // M
-    nW = (Wp - (R - 1)) // M
+    nW = (W + lo_w + hi_w - (R - 1)) // M
+    # the strip DMA slices the padded input: its column extent is padded
+    # to the f32 sublane tiling and every channel block to the lane width
+    Wp = _round_up(W + lo_w + hi_w, SUBLANES)
     if depthwise:
-        cb = min(cout_block, _round_up(C, 8))
+        cb = min(_round_up(cout_block, LANES), _round_up(C, LANES))
         Cp = _round_up(C, cb)
         kb, n_k = cb, 1
         Op, n_o = Cp, Cp // cb
@@ -419,10 +435,13 @@ def fused_geometry(algo: BilinearAlgorithm, B: int, H: int, W: int,
             rows_per_step = auto_rows_per_step(algo, B, nH, nW, Wp, cb, cb,
                                                n_k=1, n_o=n_o)
     else:
-        kb = _round_up(C, 8) if k_block is None \
-            else min(k_block, _round_up(C, 8))
+        kb = _round_up(C, LANES) if k_block is None \
+            else min(_round_up(k_block, LANES), _round_up(C, LANES))
         Cp = _round_up(C, kb)
-        cb = min(cout_block, _round_up(Cout, 8))
+        # an output channel block is the whole (8-padded) C_out or a
+        # multiple of the lane width
+        cb = _round_up(Cout, SUBLANES) if Cout <= cout_block \
+            else _round_up(cout_block, LANES)
         Op = _round_up(Cout, cb)
         n_k = Cp // kb
         n_o = Op // cb
@@ -447,181 +466,189 @@ def fused_geometry(algo: BilinearAlgorithm, B: int, H: int, W: int,
         cache_xq=cache_xq, double_buffer=double_buffer)
 
 
-def _quantize_strip_group(xg, bt, s, qmax, *, imgs: int, rows: int,
-                          n_w: int, M: int, L: int):
-    """Transform + per-frequency quantize one (imgs, span, Wp, cb) strip
-    group into the (P, imgs*rows*nW, cb) int8 matmul LHS.  Shared by the
-    dense and depthwise fused kernels so their integer grids are
-    bit-identical by construction."""
-    t = bt.shape[0]
-    q_cols = []
-    for im in range(imgs):                     # static unroll: strips
-        for r in range(rows):
-            xs = xg[im, r * M:r * M + L]       # (L, Wp, cb) f32
-            # row transform once for the whole strip; every tile
-            # column reuses it
-            rws = jnp.einsum("ti,iwc->twc", bt, xs,
-                             preferred_element_type=jnp.float32)
-            for jj in range(n_w):              # static unroll: cols
-                tx = jnp.einsum("uj,tjc->tuc", bt,
-                                rws[:, jj * M:jj * M + L, :],
-                                preferred_element_type=jnp.float32)
-                q = jnp.clip(jnp.round(tx / s[:, :, None]), -qmax, qmax)
-                q_cols.append(q.reshape(t * t, -1))    # (P, cb)
-    # (P, imgs*rows*nW, cb)
-    return jnp.stack(q_cols, axis=1).astype(jnp.int8)
+def _strip_dma(x_hbm, buf_ref, sem_ref, geom: FusedGeometry, si, sk, slot):
+    """DMA descriptor for strip group ``si`` / k-block ``sk`` into a slot."""
+    b0, row0, _, c0 = geom.strip_offset(si, sk)
+    return pltpu.make_async_copy(
+        x_hbm.at[pl.ds(b0, geom.imgs), pl.ds(row0, geom.span), :,
+                 pl.ds(c0, geom.kb)],
+        buf_ref.at[slot], sem_ref.at[slot])
 
 
-def _dequant_inverse_strip_group(y, at, t, *, imgs: int, rows: int,
-                                 n_w: int, M: int):
-    """(P, cols, cb) dequantized f32 -> (imgs, rows*M, nW*M, cb) spatial
-    output strip group (the A^T Y A correction-term inverse)."""
-    ty = y.reshape(t, t, imgs * rows, n_w, -1)
-    z = jnp.einsum("mt,tugnc->mugnc", at, ty,
-                   preferred_element_type=jnp.float32)
-    z = jnp.einsum("pu,mugnc->mgnpc", at, z,
-                   preferred_element_type=jnp.float32)
-    # (M, imgs*rows, nW, M, cb) -> (imgs, rows*M, nW*M, cb)
-    z = z.reshape(M, imgs, rows, n_w, M, -1)
-    z = jnp.transpose(z, (1, 2, 0, 3, 4, 5))
-    return z.reshape(imgs, rows * M, n_w * M, -1)
+def _for_each_strip(geom: FusedGeometry, body) -> None:
+    """``body(s, im, r)`` for every strip ``s`` of one group (image ``im``
+    of the group, tile-row ``r`` of the image)."""
+    def step(s, carry):
+        body(s, s // geom.rows, s % geom.rows)
+        return carry
+    jax.lax.fori_loop(0, geom.imgs * geom.rows, step, 0)
 
 
-def _fused_kernel(bt_ref, at_ref, sx_ref, sw_ref, x_ref, w_ref, o_ref,
-                  acc_ref, *scratch, n_w: int, M: int, L: int, bits: int,
-                  n_k: int, n_o: int, grid0: int, g_h: int, imgs: int,
-                  rows: int, span: int, kb: int, cache_xq: bool,
-                  double_buffer: bool):
+def _strip_loader(buf_ref, slot, geom: FusedGeometry, im, r):
+    """``load_col(j)`` of :func:`~repro.core.conv2d.separable_2d`: tile
+    column element ``j`` of every tile of one strip, an ``(L, nW, kb)``
+    stack of stride-M slabs."""
+    M = geom.M
+
+    def load_col(j):
+        return buf_ref[slot, im, pl.ds(r * M, geom.L),
+                       pl.ds(j, geom.nW, stride=M), :]
+    return load_col
+
+
+def _strip_store(o_ref, geom: FusedGeometry, im, r):
+    """``emit_col(n, zs)`` writing output tile column ``n`` of every tile
+    of one strip into the spatial output block at stride M."""
+    M = geom.M
+
+    def emit_col(n, zs):
+        o_ref[im, pl.ds(r * M, M), pl.ds(n, geom.nW, stride=M), :] = \
+            jnp.stack(zs)
+    return emit_col
+
+
+def _fused_kernel(inv_ref, scale_ref, x_hbm, w_ref, o_ref, acc_ref,
+                  stage_ref, *scratch, geom: FusedGeometry, bt, at,
+                  qmax: int):
     """One (strip group, C_out block, C_in block) step of the pipeline.
 
-    ``scratch`` holds, in order and each only when enabled: the
-    quantized-strip cache (``cache_xq``), then the two-slot DMA landing
-    buffer + its semaphore pair (``double_buffer``).
+    ``scratch`` holds the quantized-strip cache (only with ``cache_xq``),
+    then the strip landing buffer and its DMA semaphores.
     """
     i = pl.program_id(0)
     j = pl.program_id(1)
     k = pl.program_id(2)
+    t, P, nW, n_k = geom.t, geom.P, geom.nW, geom.n_k
+    scratch = list(scratch)
+    xq_ref = scratch.pop(0) if geom.cache_xq else None
+    buf_ref, sem_ref = scratch
 
     @pl.when(k == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bt = bt_ref[...]                               # (t, L)
-    t = bt.shape[0]
-    s = sx_ref[...]                                # (t, t)
-    qmax = 2 ** (bits - 1) - 1
+    # one strip-sequence entry per CONSUMING step: with the xq cache only
+    # j == 0 steps read the input (j > 0 replays from VMEM)
+    if geom.cache_xq:
+        s_idx, total = i * n_k + k, geom.grid0 * n_k
 
-    scratch = list(scratch)
-    xq_ref = scratch.pop(0) if cache_xq else None
+        def coords(sn):
+            return sn // n_k, sn % n_k
+    else:
+        s_idx, total = (i * geom.n_o + j) * n_k + k, \
+            geom.grid0 * geom.n_o * n_k
 
-    if double_buffer:
-        buf_ref, sem_ref = scratch
-        # one strip-sequence entry per CONSUMING step: with the xq cache
-        # only j == 0 steps touch the input (j > 0 replays from VMEM);
-        # without it every step re-reads its strip (same HBM traffic as
-        # the BlockSpec path re-fetching per C_out block)
-        if cache_xq:
-            s_idx = i * n_k + k
-            total = grid0 * n_k
+        def coords(sn):
+            return sn // (geom.n_o * n_k), sn % n_k
+    slots = geom.strip_slots
 
-            def _coords(sn):
-                return sn // n_k, sn % n_k
-        else:
-            s_idx = (i * n_o + j) * n_k + k
-            total = grid0 * n_o * n_k
+    def dma(sn):
+        return _strip_dma(x_hbm, buf_ref, sem_ref, geom, *coords(sn),
+                          sn % slots)
 
-            def _coords(sn):
-                return sn // (n_o * n_k), sn % n_k
-
-        def _dma(sn):
-            si, sk = _coords(sn)
-            bi = si // g_h
-            gi = si % g_h
-            return pltpu.make_async_copy(
-                x_ref.at[pl.ds(bi * imgs, imgs),
-                         pl.ds(gi * rows * M, span),
-                         slice(None), pl.ds(sk * kb, kb)],
-                buf_ref.at[sn % 2], sem_ref.at[sn % 2])
-
-        def _pipeline():
-            # warm-up: the very first step issues its own strip's DMA;
-            # every consuming step then prefetches the NEXT strip into
-            # the other slot before blocking on its own — the next read
-            # is in flight for the whole transform+matmul of this one
+    def consume():
+        if geom.double_buffer:
+            # the first step issues its own strip; every consuming step
+            # then prefetches the NEXT strip into the other slot before
+            # blocking on its own
             @pl.when(s_idx == 0)
             def _first():
-                _dma(0).start()
+                dma(0).start()
 
             @pl.when(s_idx + 1 < total)
             def _prefetch():
-                _dma(s_idx + 1).start()
-
-            _dma(s_idx).wait()
-
-        if cache_xq:
-            pl.when(j == 0)(_pipeline)
+                dma(s_idx + 1).start()
         else:
-            _pipeline()
+            dma(s_idx).start()
+        dma(s_idx).wait()
+        slot = s_idx % slots
 
-        def _load_group():
-            return buf_ref[s_idx % 2]              # (imgs, span, Wp, kb)
+        def quantize(s, im, r):
+            def emit_col(b, ys):
+                # frequencies p = a*t + b of one column: stride t in stage
+                stage_ref[pl.ds(b, t, stride=t), pl.ds(s * nW, nW), :] = \
+                    jnp.stack([c2d.quantize_slab(y, inv_ref[a * t + b], qmax)
+                               for a, y in enumerate(ys)])
+            c2d.separable_2d(bt, _strip_loader(buf_ref, slot, geom, im, r),
+                             emit_col)
+        _for_each_strip(geom, quantize)
+        if geom.cache_xq:
+            def fill(p, carry):
+                xq_ref[k, p] = stage_ref[p].astype(jnp.int8)
+                return carry
+            jax.lax.fori_loop(0, P, fill, 0)
+
+    if geom.cache_xq:
+        pl.when(j == 0)(consume)
     else:
-        def _load_group():
-            return x_ref[...]                      # (imgs, span, Wp, kb)
+        consume()
 
-    def _quantized_strips():
-        return _quantize_strip_group(_load_group(), bt, s, qmax, imgs=imgs,
-                                     rows=rows, n_w=n_w, M=M, L=L)
-
-    if cache_xq:
-        # strips depend on (strip group, k) only: compute on the first
-        # C_out block, replay from VMEM for the rest
-        @pl.when(j == 0)
-        def _fill_cache():
-            xq_ref[k] = _quantized_strips()
-        xq = xq_ref[k]
-    else:
-        xq = _quantized_strips()
-    w = w_ref[...]                                     # (P, kb, cb) int8
-    acc_ref[...] += jax.lax.dot_general(
-        xq, w, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32)              # (P, cols, cb)
+    # the t^2 int8 MXU matmuls, one 2-D dot per frequency
+    def matmul(p, carry):
+        xq = xq_ref[k, p] if geom.cache_xq \
+            else stage_ref[p].astype(jnp.int8)
+        acc_ref[p] += jnp.dot(xq, w_ref[p], preferred_element_type=jnp.int32)
+        return carry
+    jax.lax.fori_loop(0, P, matmul, 0)
 
     @pl.when(k == n_k - 1)
     def _finalize():
-        at = at_ref[...]                           # (M, t)
-        sw = sw_ref[...]                           # (P, cb)
-        scale = s.reshape(t * t)[:, None, None] * sw[:, None, :]
-        y = acc_ref[...].astype(jnp.float32) * scale   # (P, cols, cb)
-        o_ref[...] = _dequant_inverse_strip_group(
-            y, at, t, imgs=imgs, rows=rows, n_w=n_w, M=M).astype(o_ref.dtype)
+        # dequantize in place (f32 bits in the int32 scratch) before the
+        # inverse reads it, as the staged pipeline reads a stored tensor
+        def dequant(p, carry):
+            y = acc_ref[p].astype(jnp.float32) * scale_ref[pl.ds(p, 1), :]
+            acc_ref[p] = jax.lax.bitcast_convert_type(y, jnp.int32)
+            return carry
+        jax.lax.fori_loop(0, P, dequant, 0)
+
+        def inverse(s, im, r):
+            def load_col(b):
+                return jax.lax.bitcast_convert_type(
+                    acc_ref[pl.ds(b, t, stride=t), pl.ds(s * nW, nW), :],
+                    jnp.float32)
+            c2d.separable_2d(at, load_col, _strip_store(o_ref, geom, im, r))
+        _for_each_strip(geom, inverse)
 
 
-def _fused_dw_kernel(bt_ref, at_ref, sx_ref, sw_ref, x_ref, w_ref, o_ref, *,
-                     n_w: int, M: int, L: int, bits: int, imgs: int,
-                     rows: int):
+def _fused_dw_kernel(inv_ref, scale_ref, x_hbm, w_ref, o_ref, y_ref,
+                     buf_ref, sem_ref, *, geom: FusedGeometry, bt, at,
+                     qmax: int):
     """One (strip group, channel block) step of the depthwise pipeline.
 
     Depthwise has no channel contraction, so the grid loses the C_in
     k-dimension and the C_out blocks *are* the input channel blocks: the
     t^2 MXU matmuls collapse to a VPU elementwise int32 product against
-    the (P, cb) weight block, and no accumulator scratch (and no xq
-    cache — each channel block is consumed exactly once) is needed.
+    the (P, cb) weight block.  Each strip runs transform, product and
+    inverse back to back through the (P, nW, cb) ``y_ref`` scratch.
     """
-    bt = bt_ref[...]                               # (t, L)
-    t = bt.shape[0]
-    s = sx_ref[...]                                # (t, t)
-    qmax = 2 ** (bits - 1) - 1
-    xq = _quantize_strip_group(x_ref[...], bt, s, qmax, imgs=imgs,
-                               rows=rows, n_w=n_w, M=M, L=L)
-    w = w_ref[...]                                 # (P, cb) int8
-    prod = xq.astype(jnp.int32) * w[:, None, :].astype(jnp.int32)
-    at = at_ref[...]                               # (M, t)
-    sw = sw_ref[...]                               # (P, cb)
-    scale = s.reshape(t * t)[:, None, None] * sw[:, None, :]
-    y = prod.astype(jnp.float32) * scale           # (P, cols, cb)
-    o_ref[...] = _dequant_inverse_strip_group(
-        y, at, t, imgs=imgs, rows=rows, n_w=n_w, M=M).astype(o_ref.dtype)
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    t = geom.t
+    cp = _strip_dma(x_hbm, buf_ref, sem_ref, geom, i, j, 0)
+    cp.start()
+    cp.wait()
+
+    def strip(s, im, r):
+        def emit_col(b, ys):
+            def product(a, y):
+                p = a * t + b
+                q = c2d.quantize_slab(y, inv_ref[p], qmax).astype(jnp.int32)
+                prod = q * w_ref[pl.ds(p, 1), :].astype(jnp.int32)
+                return prod.astype(jnp.float32) * scale_ref[pl.ds(p, 1), :]
+            y_ref[b] = jnp.stack([product(a, y) for a, y in enumerate(ys)])
+        c2d.separable_2d(bt, _strip_loader(buf_ref, 0, geom, im, r),
+                         emit_col)
+        c2d.separable_2d(at, y_ref.__getitem__,
+                         _strip_store(o_ref, geom, im, r))
+    _for_each_strip(geom, strip)
+
+
+def _compiler_params():
+    # the scoped-VMEM grant: the kernel's own buffers are packed against
+    # VMEM_LIMIT_BYTES by fused_geometry; the headroom covers Mosaic's
+    # internal scratch (dot results, spilled transform slabs)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=VMEM_LIMIT_BYTES + VMEM_HEADROOM_BYTES)
 
 
 @functools.partial(jax.jit, static_argnames=("algo", "padding", "bits",
@@ -632,7 +659,7 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
                      act_scale: jnp.ndarray, w_scale: jnp.ndarray,
                      algo: BilinearAlgorithm, *,
                      padding: str = "SAME", bits: int = 8,
-                     interpret: bool = True,
+                     interpret: Optional[bool] = None,
                      k_block: Optional[int] = K_BLOCK,
                      cout_block: int = COUT_BLOCK,
                      rows_per_step: Optional[int] = 1,
@@ -642,43 +669,37 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
 
     x (B, H, W, Cin) f32; wq (t^2, Cin, Cout) int8; act_scale (t, t);
     w_scale (t, t, Cout) -> (B, H', W', Cout) f32.  Numerically identical
-    to the staged ``quantized_fastconv2d`` (same integer grid and scales)
-    at every grouping.  ``bits`` sets the activation clipping grid
-    (sub-int8 policies run on the int8 carrier).  ``k_block=None`` means
-    full K: the whole C_in reduction in a single k-block (``n_k = 1``) —
-    the autotuner's "no reduction grid dim" candidate, same convention as
-    the staged ``tdmm_int8``.  ``rows_per_step`` folds that many
-    tile-rows (counting across images once one image's rows are
-    exhausted — see :func:`grouping`) into a single grid step;
-    ``None`` picks the largest budget-fitting group via
-    :func:`auto_rows_per_step`.  ``double_buffer`` switches the input
-    strip reads to a manually DMA-pipelined two-slot VMEM buffer
-    (prefetch of strip s+1 overlaps compute on strip s).
+    to the staged ``quantized_fastconv2d`` (same transform arithmetic,
+    integer grid and scales) at every grouping.  ``bits`` sets the
+    activation clipping grid (sub-int8 policies run on the int8 carrier).
+    ``k_block=None`` means full K: the whole C_in reduction in a single
+    k-block (``n_k = 1``).  ``rows_per_step`` folds that many tile-rows
+    (counting across images once one image's rows are exhausted — see
+    :func:`grouping`) into a single grid step; ``None`` picks the largest
+    budget-fitting group via :func:`auto_rows_per_step`.
+    ``double_buffer`` prefetches the next strip group into a second VMEM
+    slot while the current one is transformed and matmul'd.
+    ``interpret=None`` compiles for a TPU and interprets elsewhere
+    (:func:`repro.runtime.resolve_interpret`).
 
     ``depthwise`` (wq (t^2, 1, C), w_scale (t, t, C)) swaps the t^2 MXU
     matmuls for the transform-domain elementwise product
     (``_fused_dw_kernel``): the grid drops the C_in reduction dim and
     blocks over the shared in==out channel axis instead.  ``k_block``
     and ``double_buffer`` are no-ops there — there is no reduction to
-    block, and each channel block's strip is read exactly once, so the
-    two-slot DMA pipeline has no cross-block reuse to overlap (the knobs
-    are accepted so one ``KernelConfig`` sweep serves both layouts;
-    every config remains bit-identical).
+    block, and each channel block's strip is read exactly once.
     """
+    interpret = resolve_interpret(interpret)
     B, H, W, C = x.shape
-    t, M, R, L = algo.t, algo.M, algo.R, algo.L
+    t, M, R = algo.t, algo.M, algo.R
     P = t * t
     if depthwise:
         assert wq.shape == (P, 1, C), (wq.shape, P, C)
     else:
         assert wq.shape[0] == P and wq.shape[1] == C, (wq.shape, P, C)
     Cout = wq.shape[2]
-    lo_h, hi_h, out_h = c2d.pad_amounts(H, M, R, padding)
-    lo_w, hi_w, out_w = c2d.pad_amounts(W, M, R, padding)
-    xp = jnp.pad(x, ((0, 0), (lo_h, hi_h), (lo_w, hi_w), (0, 0)))
-    nH = (xp.shape[1] - (R - 1)) // M
-    nW = (xp.shape[2] - (R - 1)) // M
-    Wp = xp.shape[2]
+    lo_h, _, out_h = c2d.pad_amounts(H, M, R, padding)
+    lo_w, _, out_w = c2d.pad_amounts(W, M, R, padding)
     # the ONE geometry derivation (grid, channel blocking, grouping, strip
     # spans, scratch set) — shared verbatim with the static resource
     # checker (repro.analysis.kernel_checks) and the serving batcher
@@ -686,126 +707,66 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
                           k_block=k_block, cout_block=cout_block,
                           rows_per_step=rows_per_step,
                           double_buffer=double_buffer, depthwise=depthwise)
-    if depthwise:
-        return _fused_depthwise(xp, wq, act_scale, w_scale, algo, geom,
-                                out_h=out_h, out_w=out_w, bits=bits,
-                                interpret=interpret)
-
-    kb, Cp, cb, Op = geom.kb, geom.Cp, geom.cb, geom.Op
-    n_k, n_o = geom.n_k, geom.n_o
-    imgs, rows, g_h, nH_p = geom.imgs, geom.rows, geom.g_h, geom.nH_p
-    span, grid0 = geom.span, geom.grid0
-
     # grouped-grid padding: strips of the last group read rows up to
     # (nH_p - 1) * M + L; the extra zero rows produce output rows that are
     # sliced off below.  Channel dims pad with zeros; zero channels
     # quantize to zero / carry zero scales, so they contribute nothing.
-    xp = jnp.pad(xp, ((0, 0), (0, geom.x_rows - xp.shape[1]), (0, 0),
-                      (0, Cp - C)))
-    wqp = jnp.pad(wq, ((0, 0), (0, Cp - C), (0, Op - Cout)))
-    sw = jnp.pad(w_scale.reshape(P, Cout).astype(jnp.float32),
-                 ((0, 0), (0, Op - Cout)))
+    xp = jnp.pad(x, ((0, 0), (lo_h, geom.x_rows - H - lo_h),
+                     (lo_w, geom.w_padded - W - lo_w), (0, geom.Cp - C)))
+    inv = c2d.reciprocal_scale(act_scale).reshape(P)
+    scale = jnp.pad(c2d.dequant_scale(act_scale, w_scale),
+                    ((0, 0), (0, geom.Op - Cout)))
+    bt, at = c2d.transform_coefficients(algo)
+    qmax = 2 ** (bits - 1) - 1
+    kb, cb, M = geom.kb, geom.cb, geom.M
+    strip_buf = [pltpu.VMEM((geom.strip_slots,) + geom.strip_shape,
+                            jnp.float32),
+                 pltpu.SemaphoreType.DMA((geom.strip_slots,))]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out_block = (geom.imgs, geom.rows * M, geom.nW * M, cb)
+    out_shape = jax.ShapeDtypeStruct((B, geom.nH_p * M, geom.nW * M, geom.Op),
+                                     jnp.float32)
+    if depthwise:
+        kern = functools.partial(_fused_dw_kernel, geom=geom, bt=bt, at=at,
+                                 qmax=qmax)
+        out = pl.pallas_call(
+            kern,
+            grid=geom.grid,
+            in_specs=[smem,
+                      pl.BlockSpec((P, cb), lambda i, j: (0, j)),
+                      hbm,
+                      pl.BlockSpec((P, cb), lambda i, j: (0, j))],
+            out_specs=pl.BlockSpec(
+                out_block, lambda i, j: geom.out_index(i, j)),
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((t, t, geom.nW, cb), jnp.float32)]
+            + strip_buf,
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+        )(inv, scale, xp,
+          jnp.pad(wq.reshape(P, C), ((0, 0), (0, geom.Cp - C))))
+        return out[:, :out_h, :out_w, :C]
 
-    cols = geom.cols
-    cache_xq = geom.cache_xq
-    bt_f32, _, at_f32 = c2d.transform_matrices(algo, "float32")
-    kern = functools.partial(
-        _fused_kernel, n_w=nW, M=M, L=L, bits=bits, n_k=n_k, n_o=n_o,
-        grid0=grid0, g_h=g_h, imgs=imgs, rows=rows, span=span, kb=kb,
-        cache_xq=cache_xq, double_buffer=double_buffer)
-    if double_buffer:
-        # the strips land via manual DMA from HBM: the operand never
-        # enters the automatic BlockSpec pipeline
-        x_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    else:
-        # overlapping (span, Wp) strip groups at row stride rows*M,
-        # straight from HBM — element-offset (Unblocked) index map
-        x_spec = pl.BlockSpec(
-            (imgs, span, Wp, kb),
-            lambda i, j, k, _gh=g_h, _im=imgs, _rm=rows * M:
-            ((i // _gh) * _im, (i % _gh) * _rm, 0, k * kb),
-            indexing_mode=pl.Unblocked())
-    scratch_shapes = [pltpu.VMEM((P, cols, cb), jnp.int32)]
-    if cache_xq:
-        scratch_shapes.append(pltpu.VMEM((n_k, P, cols, kb), jnp.int8))
-    if double_buffer:
-        scratch_shapes += [pltpu.VMEM((2, imgs, span, Wp, kb), jnp.float32),
-                           pltpu.SemaphoreType.DMA((2,))]
+    wqp = jnp.pad(wq, ((0, 0), (0, geom.Cp - C), (0, geom.Op - Cout)))
+    scratch_shapes = [pltpu.VMEM((P, geom.cols, cb), jnp.int32),
+                      pltpu.VMEM((P, geom.cols, kb), jnp.float32)]
+    if geom.cache_xq:
+        scratch_shapes.append(
+            pltpu.VMEM((geom.n_k, P, geom.cols, kb), jnp.int8))
+    kern = functools.partial(_fused_kernel, geom=geom, bt=bt, at=at,
+                             qmax=qmax)
     out = pl.pallas_call(
         kern,
-        grid=(grid0, n_o, n_k),
-        in_specs=[
-            pl.BlockSpec((t, L), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((M, t), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((t, t), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((P, cb), lambda i, j, k: (0, j)),
-            x_spec,
-            pl.BlockSpec((P, kb, cb), lambda i, j, k: (0, k, j)),
-        ],
-        out_specs=pl.BlockSpec((imgs, rows * M, nW * M, cb),
-                               lambda i, j, k, _gh=g_h: (i // _gh, i % _gh,
-                                                         0, j)),
-        out_shape=jax.ShapeDtypeStruct((B, nH_p * M, nW * M, Op),
-                                       jnp.float32),
-        scratch_shapes=scratch_shapes,
+        grid=geom.grid,
+        in_specs=[smem,
+                  pl.BlockSpec((P, cb), lambda i, j, k: (0, j)),
+                  hbm,
+                  pl.BlockSpec((P, kb, cb), lambda i, j, k: (0, k, j))],
+        out_specs=pl.BlockSpec(out_block, geom.out_index),
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes + strip_buf,
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(bt_f32, at_f32, act_scale.astype(jnp.float32), sw, xp, wqp)
+    )(inv, scale, xp, wqp)
     return out[:, :out_h, :out_w, :Cout]
-
-
-def _fused_depthwise(xp, wq, act_scale, w_scale, algo, geom, *, out_h,
-                     out_w, bits, interpret):
-    """Depthwise half of :func:`sfc_fused_conv2d` (input already padded).
-
-    Grid = (strip groups, channel blocks): the channel axis is both the
-    input and the output blocking (zero-padded channels quantize to zero
-    and carry zero scales, contributing nothing).  ``geom`` carries the
-    resolved :class:`FusedGeometry` (``rows_per_step`` auto-resolution
-    over-counts depthwise slightly — the dense budget includes a weight
-    k-block and an int32 accumulator the dw kernel does not allocate — a
-    safe bound, never an overflow).
-    """
-    B = xp.shape[0]
-    C = wq.shape[2]
-    t, M, L = algo.t, algo.M, algo.L
-    P = t * t
-    Wp = xp.shape[2]
-    nH, nW = geom.nH, geom.nW
-    cb, Cp, n_c = geom.cb, geom.Cp, geom.n_o
-    imgs, rows, g_h = geom.imgs, geom.rows, geom.g_h
-    span, grid0 = geom.span, geom.grid0
-
-    xp = jnp.pad(xp, ((0, 0), (0, geom.x_rows - xp.shape[1]), (0, 0),
-                      (0, Cp - C)))
-    wqp = jnp.pad(wq.reshape(P, C), ((0, 0), (0, Cp - C)))
-    sw = jnp.pad(w_scale.reshape(P, C).astype(jnp.float32),
-                 ((0, 0), (0, Cp - C)))
-    bt_f32, _, at_f32 = c2d.transform_matrices(algo, "float32")
-
-    kern = functools.partial(_fused_dw_kernel, n_w=nW, M=M, L=L, bits=bits,
-                             imgs=imgs, rows=rows)
-    out = pl.pallas_call(
-        kern,
-        grid=(grid0, n_c),
-        in_specs=[
-            pl.BlockSpec((t, L), lambda i, j: (0, 0)),
-            pl.BlockSpec((M, t), lambda i, j: (0, 0)),
-            pl.BlockSpec((t, t), lambda i, j: (0, 0)),
-            pl.BlockSpec((P, cb), lambda i, j: (0, j)),
-            # overlapping (span, Wp) strip groups at row stride rows*M,
-            # channel-blocked by j — element-offset (Unblocked) index map
-            pl.BlockSpec(
-                (imgs, span, Wp, cb),
-                lambda i, j, _gh=g_h, _im=imgs, _rm=rows * M:
-                ((i // _gh) * _im, (i % _gh) * _rm, 0, j * cb),
-                indexing_mode=pl.Unblocked()),
-            pl.BlockSpec((P, cb), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((imgs, rows * M, nW * M, cb),
-                               lambda i, j, _gh=g_h: (i // _gh, i % _gh,
-                                                      0, j)),
-        out_shape=jax.ShapeDtypeStruct((B, geom.nH_p * M, nW * M, Cp),
-                                       jnp.float32),
-        interpret=interpret,
-    )(bt_f32, at_f32, act_scale.astype(jnp.float32), sw, xp, wqp)
-    return out[:, :out_h, :out_w, :C]

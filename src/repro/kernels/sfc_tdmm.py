@@ -34,21 +34,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import resolve_interpret
+
 T_BLOCK = 128
 N_BLOCK = 128
 
 
-def _tdmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref):
+def _tdmm_kernel(x_ref, w_ref, s_ref, o_ref):
     x = x_ref[0]                                     # (bt, K) int8
     w = w_ref[0]                                     # (K, bn) int8
-    acc = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)            # (bt, bn) int32
-    scale = sx_ref[0] * sw_ref[0]                    # (bn,) f32
-    o_ref[0] = acc.astype(jnp.float32) * scale[None, :]
+    acc = jnp.dot(x, w, preferred_element_type=jnp.int32)   # (bt, bn)
+    o_ref[0] = acc.astype(jnp.float32) * s_ref[0]   # (1, bn) f32 scale
 
 
-def _tdmm_kblock_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
+def _tdmm_kblock_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *,
                         n_k: int):
     k = pl.program_id(3)
 
@@ -58,22 +57,18 @@ def _tdmm_kblock_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_ref, *,
 
     x = x_ref[0]                                     # (bt, bk) int8
     w = w_ref[0]                                     # (bk, bn) int8
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)            # (bt, bn) int32
+    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _dequant():
-        scale = sx_ref[0] * sw_ref[0]                # (bn,) f32
-        o_ref[0] = acc_ref[...].astype(jnp.float32) * scale[None, :]
+        o_ref[0] = acc_ref[...].astype(jnp.float32) * s_ref[0]
 
 
-def _tdmm_dw_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref):
+def _tdmm_dw_kernel(x_ref, w_ref, s_ref, o_ref):
     x = x_ref[0].astype(jnp.int32)                   # (bt, bc)
-    w = w_ref[0].astype(jnp.int32)                   # (bc,)
-    prod = x * w[None, :]                            # exact int32 products
-    scale = sx_ref[0] * sw_ref[0]                    # (bc,) f32
-    o_ref[0] = prod.astype(jnp.float32) * scale[None, :]
+    w = w_ref[0].astype(jnp.int32)                   # (1, bc)
+    prod = x * w                                     # exact int32 products
+    o_ref[0] = prod.astype(jnp.float32) * s_ref[0]  # (1, bc) f32 scale
 
 
 def _pad_to(x, axis, mult):
@@ -85,22 +80,29 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, width)
 
 
+def _scale_blocks(sx, sw, n_block):
+    """(P, 1, Np) combined dequant scale ``sx[p] * sw[p, n]`` — the same
+    product :func:`repro.core.conv2d.dequant_scale` gives the fused kernel;
+    its (1, 1, n_block) blocks are tiling-legal on the chip."""
+    s = sx.astype(jnp.float32)[:, None] * sw.astype(jnp.float32)
+    return _pad_to(s, 1, n_block)[:, None, :]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "t_block",
                                              "n_block", "k_block"))
 def tdmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
-              sw: jnp.ndarray, *, interpret: bool = True,
+              sw: jnp.ndarray, *, interpret: Optional[bool] = None,
               t_block: int = T_BLOCK, n_block: int = N_BLOCK,
               k_block: Optional[int] = None) -> jnp.ndarray:
     """X (P, T, K) int8 x W (P, K, N) int8 -> (P, T, N) f32."""
+    interpret = resolve_interpret(interpret)
     P, T, K = xq.shape
     _, _, N = wq.shape
     assert wq.shape == (P, K, N) and sx.shape == (P,) and sw.shape == (P, N)
     xq = _pad_to(xq, 1, t_block)
     wq = _pad_to(wq, 2, n_block)
-    sw_p = _pad_to(sw, 1, n_block)
+    scale = _scale_blocks(sx, sw, n_block)
     Tp, Np = xq.shape[1], wq.shape[2]
-    sx = sx.astype(jnp.float32)
-    sw_p = sw_p.astype(jnp.float32)
     if k_block is None or k_block >= K:
         out = pl.pallas_call(
             _tdmm_kernel,
@@ -108,14 +110,13 @@ def tdmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((1, t_block, K), lambda p, i, j: (p, i, 0)),
                 pl.BlockSpec((1, K, n_block), lambda p, i, j: (p, 0, j)),
-                pl.BlockSpec((1,), lambda p, i, j: (p,)),
-                pl.BlockSpec((1, n_block), lambda p, i, j: (p, j)),
+                pl.BlockSpec((1, 1, n_block), lambda p, i, j: (p, 0, j)),
             ],
             out_specs=pl.BlockSpec((1, t_block, n_block),
                                    lambda p, i, j: (p, i, j)),
             out_shape=jax.ShapeDtypeStruct((P, Tp, Np), jnp.float32),
             interpret=interpret,
-        )(xq, wq, sx, sw_p)
+        )(xq, wq, scale)
         return out[:, :T, :N]
     # k-blocked reduction: zero-padded K tail contributes nothing
     xq = _pad_to(xq, 2, k_block)
@@ -131,22 +132,21 @@ def tdmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
                          lambda p, i, j, k: (p, i, k)),
             pl.BlockSpec((1, k_block, n_block),
                          lambda p, i, j, k: (p, k, j)),
-            pl.BlockSpec((1,), lambda p, i, j, k: (p,)),
-            pl.BlockSpec((1, n_block), lambda p, i, j, k: (p, j)),
+            pl.BlockSpec((1, 1, n_block), lambda p, i, j, k: (p, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, t_block, n_block),
                                lambda p, i, j, k: (p, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Tp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((t_block, n_block), jnp.int32)],
         interpret=interpret,
-    )(xq, wq, sx, sw_p)
+    )(xq, wq, scale)
     return out[:, :T, :N]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "t_block",
                                              "n_block"))
 def tdmm_int8_depthwise(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
-                        sw: jnp.ndarray, *, interpret: bool = True,
+                        sw: jnp.ndarray, *, interpret: Optional[bool] = None,
                         t_block: int = T_BLOCK,
                         n_block: int = N_BLOCK) -> jnp.ndarray:
     """X (P, T, C) int8 x W (P, C) int8 -> (P, T, C) f32, elementwise.
@@ -160,21 +160,20 @@ def tdmm_int8_depthwise(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
         (xq.shape, wq.shape, sx.shape, sw.shape)
     xq = _pad_to(xq, 1, t_block)
     xq = _pad_to(xq, 2, n_block)
-    wq_p = _pad_to(wq, 1, n_block)
-    sw_p = _pad_to(sw, 1, n_block).astype(jnp.float32)
+    wq_p = _pad_to(wq, 1, n_block)[:, None, :]
+    scale = _scale_blocks(sx, sw, n_block)
     Tp, Cp = xq.shape[1], xq.shape[2]
     out = pl.pallas_call(
         _tdmm_dw_kernel,
         grid=(P, Tp // t_block, Cp // n_block),
         in_specs=[
             pl.BlockSpec((1, t_block, n_block), lambda p, i, j: (p, i, j)),
-            pl.BlockSpec((1, n_block), lambda p, i, j: (p, j)),
-            pl.BlockSpec((1,), lambda p, i, j: (p,)),
-            pl.BlockSpec((1, n_block), lambda p, i, j: (p, j)),
+            pl.BlockSpec((1, 1, n_block), lambda p, i, j: (p, 0, j)),
+            pl.BlockSpec((1, 1, n_block), lambda p, i, j: (p, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, t_block, n_block),
                                lambda p, i, j: (p, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Tp, Cp), jnp.float32),
-        interpret=interpret,
-    )(xq, wq_p, sx.astype(jnp.float32), sw_p)
+        interpret=resolve_interpret(interpret),
+    )(xq, wq_p, scale)
     return out[:, :T, :C]

@@ -6,18 +6,26 @@ this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: sharding propagates as in GSPMD jit (jax.make_mesh
+    # defaults to Explicit axes, which every consumer here would have to
+    # annotate)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Whatever this host actually has (tests / examples / smoke runs)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def make_forced_host_mesh(shape, axes=("data", "model")):

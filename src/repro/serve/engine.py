@@ -73,6 +73,7 @@ from repro.serve.batcher import (AdmissionPolicy, Batch, BatchQueue,
                                  SchedulerPolicy, fold_rows_per_step)
 from repro.serve.bucketing import Bucket, BucketTable
 from repro.serve.metrics import MetricsRegistry
+from repro.runtime import resolve_interpret
 from repro.serve.types import (BATCH, QuarantinedError, Request,
                                RejectedError, Result, ShedError, SLOClass)
 
@@ -82,7 +83,7 @@ class Engine:
 
     def __init__(self, w, buckets: BucketTable, *,
                  backend: str = "pallas", algo: str = "auto",
-                 interpret: bool = True, max_batch: int = 8,
+                 interpret: Optional[bool] = None, max_batch: int = 8,
                  admission: Optional[AdmissionPolicy] = None,
                  cache: Optional[sc.ServingCache] = None,
                  metrics: Optional[MetricsRegistry] = None,
@@ -96,7 +97,7 @@ class Engine:
         self.buckets = buckets
         self.backend = backend
         self.algo = algo
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.max_batch = int(max_batch)
         self.admission = admission or AdmissionPolicy()
         self.cache = cache if cache is not None else sc.ServingCache()

@@ -215,21 +215,25 @@ def test_transform_matrices_cached_and_frozen():
 
 
 def test_cached_matrices_bit_identical_to_call_time_cast():
-    # the sfc_transform kernels used to cast bt at every call
-    # (bt.astype(tiles.dtype)); the hoist must be bit-identical
+    # the sfc_transform kernels used to cast bt at every call; they now
+    # take the algorithm's static coefficients, cached per algorithm, and
+    # the cached device matrices must equal a call-time cast bit for bit
     from repro.kernels.sfc_transform import sfc_transform
     algo = registry.get_algorithm("sfc6_6")
+    assert c2d.transform_coefficients(algo) is \
+        c2d.transform_coefficients(algo)
     rng = np.random.default_rng(1)
-    tiles = jnp.asarray(rng.standard_normal((5, algo.L, algo.L, 3)),
-                        dtype=jnp.float32)
+    x = jnp.asarray(rng.standard_normal((5, algo.L, algo.L, 3)),
+                    dtype=jnp.float32)
+    tiles = jnp.transpose(x, (1, 2, 0, 3))          # (L, L, nT, C)
     bt_cached = c2d.transform_matrices(algo, "float32")[0]
     bt_fresh = jnp.asarray(np.asarray(algo.bt()), jnp.float32)
-    out_cached = sfc_transform(tiles, bt_cached)
-    out_fresh = sfc_transform(tiles, bt_fresh)
-    assert jnp.array_equal(out_cached, out_fresh)
-    # and the fp reference path agrees with itself across dtypes handed in
-    tx_a, _ = c2d.transform_input_2d(tiles, algo, padding="VALID")
-    tx_b, _ = c2d.transform_input_2d(tiles, algo, padding="VALID")
+    assert jnp.array_equal(bt_cached, bt_fresh)
+    assert jnp.array_equal(sfc_transform(tiles, algo),
+                           sfc_transform(tiles, algo))
+    # and the fp reference path agrees with itself across calls
+    tx_a, _ = c2d.transform_input_2d(x, algo, padding="VALID")
+    tx_b, _ = c2d.transform_input_2d(x, algo, padding="VALID")
     assert jnp.array_equal(tx_a, tx_b)
 
 

@@ -38,14 +38,20 @@ def test_geometry_matches_kernel_docstring_values():
     # B=2 12x12 16->24 with sfc4_4 (M=4, t=7)
     geom = sf.fused_geometry(ALGO, 2, 12, 12, 16, 24)
     assert geom.grid == (6, 1, 1)
-    assert geom.strip_shape == (1, 6, 14, 16)
-    assert geom.vmem_bytes() == 51536
-    assert geom.scratch_shapes() == (("acc", (49, 3, 24), "int32"),)
+    # the strip is padded to the chip's tiling: 14 -> 16 columns (8
+    # sublanes), 16 -> 128 input channels (lanes)
+    assert geom.strip_shape == (1, 6, 16, 128)
+    assert geom.vmem_bytes() == 458208
+    assert geom.scratch_shapes() == (
+        ("acc", (49, 3, 24), "int32"), ("stage", (49, 3, 128), "float32"),
+        ("strip_buf", (1, 1, 6, 16, 128), "float32"))
     assert geom.rmw_axis == 2
     dw = sf.fused_geometry(ALGO, 2, 8, 8, 20, 20, depthwise=True)
     assert dw.grid == (4, 1)
-    assert dw.kb == dw.cb == 24 and dw.n_k == 1
-    assert dw.scratch_shapes() == ()
+    assert dw.kb == dw.cb == 128 and dw.n_k == 1
+    assert dw.scratch_shapes() == (
+        ("y", (7, 7, 2, 128), "float32"),
+        ("strip_buf", (1, 1, 6, 16, 128), "float32"))
     assert dw.rmw_axis is None
 
 

@@ -4,6 +4,7 @@ import json
 import pathlib
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -60,7 +61,8 @@ def test_elastic_restore_resharding(tmp_path):
     ck = Checkpointer(str(tmp_path))
     tree = _tree()
     ck.save(3, tree, blocking=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     from jax.sharding import NamedSharding, PartitionSpec as P
     shardings = jax.tree_util.tree_map(
         lambda a: NamedSharding(mesh, P(*([None] * a.ndim))), tree)

@@ -19,9 +19,9 @@ ALGO_SET = [(4, 4, 3), (6, 6, 3), (6, 7, 3)]
 def test_transform_kernel_sweep(nmr, n_tiles, channels, dtype):
     algo = generate_sfc(*nmr)
     rng = np.random.RandomState(0)
-    tiles = jnp.asarray(rng.randn(n_tiles, algo.L, algo.L, channels), dtype)
+    tiles = jnp.asarray(rng.randn(algo.L, algo.L, n_tiles, channels), dtype)
     bt = jnp.asarray(algo.bt(), dtype)
-    out = sfc_transform(tiles, bt)
+    out = sfc_transform(tiles, algo)
     want = ref.sfc_transform_ref(tiles, bt)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -32,12 +32,12 @@ def test_transform_kernel_sweep(nmr, n_tiles, channels, dtype):
 def test_transform_quantize_kernel_bitexact(nmr):
     algo = generate_sfc(*nmr)
     rng = np.random.RandomState(1)
-    tiles = jnp.asarray(rng.randn(7, algo.L, algo.L, 33), jnp.float32)
+    tiles = jnp.asarray(rng.randn(algo.L, algo.L, 7, 33), jnp.float32)
     bt = jnp.asarray(algo.bt(), jnp.float32)
     scale = jnp.abs(ref.sfc_transform_ref(tiles, bt)).max(
-        axis=(0, 3)) / 127 + 1e-9
-    out = sfc_transform_quantize(tiles, bt, scale)
-    want = ref.sfc_transform_quantize_ref(tiles, bt, scale)
+        axis=(2, 3)) / 127 + 1e-9
+    out = sfc_transform_quantize(tiles, algo, scale)
+    want = ref.sfc_transform_quantize_ref(tiles, algo, scale)
     assert out.dtype == jnp.int8
     assert bool(jnp.all(out == want))
 
@@ -59,9 +59,9 @@ def test_tdmm_kernel_sweep(P, T, K, N):
 def test_inverse_kernel(nmr):
     algo = generate_sfc(*nmr)
     rng = np.random.RandomState(3)
-    ty = jnp.asarray(rng.randn(5, algo.t, algo.t, 21), jnp.float32)
+    ty = jnp.asarray(rng.randn(algo.t, algo.t, 5, 21), jnp.float32)
     at = jnp.asarray(algo.at(), jnp.float32)
-    out = sfc_inverse(ty, at)
+    out = sfc_inverse(ty, algo)
     want = ref.sfc_inverse_ref(ty, at)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

@@ -153,7 +153,8 @@ def test_extract_tiles_single_gather_parity():
     for padding in ("SAME", "VALID"):
         tiles, geom = ops.extract_tiles(x, algo, padding)
         bt = jnp.asarray(algo.bt(), jnp.float32)
-        got = jnp.einsum("ti,nijc,uj->ntuc", bt, tiles, bt)
+        got = jnp.einsum("ti,ijnc,uj->ntuc", bt, tiles, bt,
+                         precision="highest")
         want, _ = c2d.transform_input_2d(x, algo, padding)
         want = want.reshape(-1, algo.t, algo.t, x.shape[-1])
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -1,5 +1,6 @@
 """MoE grouped dispatch: routing semantics, capacity, shard-local grouping."""
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -82,7 +83,8 @@ def test_shard_local_grouping_matches_global():
                     jnp.float32)
     cf = cfg.n_experts / cfg.n_experts_active
     y1, _ = moe_block(p, cfg, x, capacity_factor=cf)   # ds = 1 (no rules)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     try:
         acts.install(mesh, ("data",))
         y2, _ = moe_block(p, cfg, x, capacity_factor=cf)

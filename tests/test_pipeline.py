@@ -28,7 +28,8 @@ def test_gpipe_matches_sequential():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_apply, stack_params_for_stages
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     L, d = 8, 16
     rng = np.random.RandomState(0)
     Ws = jnp.asarray(rng.randn(L, d, d) * 0.2, jnp.float32)
@@ -67,7 +68,8 @@ def test_sharding_rules_lower_small_mesh():
     from repro.train import steps as steps_lib
     from repro.configs.base import ShapeConfig
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in ["qwen3-14b", "mixtral-8x7b", "mamba2-1.3b"]:
         cfg = get_smoke_config(arch)
         model = build(cfg)
@@ -105,7 +107,8 @@ def test_sharded_train_step_executes():
     from repro.train import steps as steps_lib
     from repro.data import SyntheticTokenPipeline, TokenPipelineConfig
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_smoke_config("qwen3-14b")
     model = build(cfg)
     opt = AdamW(lr=5e-3)
