@@ -1,0 +1,8 @@
+"""Device, serving cells: 1 - (union of device op intervals / traced
+window), in %."""
+
+
+def read(rec):
+    if rec.get("kind") != "serve":
+        return None
+    return 100.0 * rec["trace"]["idle_share"]
