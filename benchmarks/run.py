@@ -12,11 +12,7 @@ the batched multi-tile-row fused variant) and APPENDS a timestamped
 git-SHA entry to ``BENCH_conv.json["trajectory"]``, so the accumulated
 history rides the committed file across PRs.  ``scaleout`` appends the
 SPMD per-shard-count rows to the same artifact (it needs two or more
-devices in this process); ``serving`` appends the open-loop
-continuous-batching SLO rows (``repro.serve`` engine, p50/p95/p99 +
-goodput + occupancy + cache hit rate) under the ``"serving"`` key;
-``chaos`` appends goodput/SLO under injected fault rates plus breaker
-recovery time under the ``"chaos"`` key; ``roofline`` appends the
+devices in this process); ``roofline`` appends the
 dry-run roofline cells under ``"roofline"``; ``costmodel`` fits the
 analytic cost model and appends its predicted-vs-measured validation
 (rank correlation, top-1/top-k agreement, coefficients) under the
@@ -32,9 +28,8 @@ def main() -> None:
     from repro.runtime import use_compilation_cache
     use_compilation_cache(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    from benchmarks import (appendixB_iterative, chaos,
-                            fig4_accuracy_vs_bops, fig5_layer_mse,
-                            roofline, scaleout, serving,
+    from benchmarks import (appendixB_iterative, fig4_accuracy_vs_bops,
+                            fig5_layer_mse, roofline, scaleout,
                             table1_algorithms, table3_throughput,
                             table45_granularity)
     suites = {
@@ -47,8 +42,6 @@ def main() -> None:
         "roofline": roofline.run,
         "costmodel": roofline.run_costmodel,
         "scaleout": scaleout.run,
-        "serving": serving.run,
-        "chaos": chaos.run,
     }
     selected = sys.argv[1:] or list(suites)
     t0 = time.time()

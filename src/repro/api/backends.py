@@ -21,6 +21,12 @@ so the Pallas backend deliberately reuses it rather than shipping a worse
 kernel.  The registry is open so future backends (GPU pallas, sharded,
 batched serving) plug in via :func:`register_backend` without touching
 call sites.
+
+Each backend runs the datapath it chose under
+``jax.named_scope("plan.<datapath>")`` (``fused``, ``staged``, ``direct``
+or ``reference``), so a device trace names the datapath of every conv
+op, a resilience fallback level included.  The scope is HLO metadata
+only.
 """
 from __future__ import annotations
 
@@ -36,6 +42,10 @@ import repro.quant.fake_quant as fq
 
 def _add_bias(y: jnp.ndarray, bias) -> jnp.ndarray:
     return y if bias is None else y + bias
+
+
+def _scope(datapath: str):
+    return jax.named_scope(f"plan.{datapath}")
 
 
 def _check_hook_supported(plan, elementwise_hook, prep) -> None:
@@ -75,7 +85,13 @@ class ReferenceBackend:
         faults.maybe_fault(faults.APPLY_REFERENCE, detail=plan)
         _check_hook_supported(plan, elementwise_hook, prep)
         if plan.algorithm is None:
-            return _direct(plan, x, prep, bias)
+            with _scope("direct"):
+                return _direct(plan, x, prep, bias)
+        with _scope("reference"):
+            return self._fast(plan, x, prep, bias, elementwise_hook)
+
+    @staticmethod
+    def _fast(plan, x, prep, bias, elementwise_hook):
         algo = plan.algorithm
         if plan.spec.rank == 1:
             if elementwise_hook is not None:
@@ -139,55 +155,66 @@ class PallasBackend:
             # no Pallas kernels for these; the reference impls are optimal
             # (XLA native conv) or trivially bandwidth-bound.
             return _REFERENCE.apply(plan, x, prep, bias=bias)
-        from repro.kernels import ops
-        algo = plan.algorithm
-        depthwise = plan.spec.depthwise
-        if prep.quantized:
-            from repro.api import tuning
-            cfg = plan.config or tuning.DEFAULT_FUSED
-            bits = plan.spec.quant.bits_act
+        if not prep.quantized:
+            with _scope("staged"):
+                return _add_bias(self._fp(plan, x, prep), bias)
+        from repro.api import tuning
+        cfg = plan.config or tuning.DEFAULT_FUSED
+        with _scope(cfg.datapath):
             if cfg.datapath == "staged":
-                faults.maybe_fault(faults.APPLY_STAGED, detail=plan)
-                if depthwise:
-                    y = ops.quantized_fastconv2d_depthwise(
-                        x, prep.wq, prep.act_scale, prep.w_scale, algo,
-                        padding=plan.spec.padding, bits=bits,
-                        interpret=plan.interpret,
-                        tile_block=cfg.tile_block,
-                        chan_block=cfg.chan_block)
-                else:
-                    y = ops.quantized_fastconv2d(
-                        x, prep.wq, prep.act_scale, prep.w_scale, algo,
-                        padding=plan.spec.padding, bits=bits,
-                        interpret=plan.interpret, k_block=cfg.k_block,
-                        tile_block=cfg.tile_block, chan_block=cfg.chan_block)
-                y = faults.maybe_corrupt(faults.APPLY_STAGED, y,
-                                         detail=plan)
+                y = self._staged_int8(plan, x, prep, cfg)
             else:
-                from repro.kernels.sfc_fused import sfc_fused_conv2d
-                faults.maybe_fault(faults.APPLY_FUSED, detail=plan)
-                y = sfc_fused_conv2d(
-                    x, prep.wq, prep.act_scale, prep.w_scale, algo,
-                    padding=plan.spec.padding, bits=bits,
-                    interpret=plan.interpret, depthwise=depthwise,
-                    k_block=cfg.k_block, cout_block=cfg.cout_block,
-                    rows_per_step=cfg.rows_per_step,
-                    double_buffer=cfg.double_buffer)
-                y = faults.maybe_corrupt(faults.APPLY_FUSED, y,
-                                         detail=plan)
+                y = self._fused_int8(plan, x, prep, cfg)
             return _add_bias(y, bias)
+
+    @staticmethod
+    def _staged_int8(plan, x, prep, cfg):
+        from repro.kernels import ops
+        faults.maybe_fault(faults.APPLY_STAGED, detail=plan)
+        bits = plan.spec.quant.bits_act
+        if plan.spec.depthwise:
+            y = ops.quantized_fastconv2d_depthwise(
+                x, prep.wq, prep.act_scale, prep.w_scale, plan.algorithm,
+                padding=plan.spec.padding, bits=bits,
+                interpret=plan.interpret, tile_block=cfg.tile_block,
+                chan_block=cfg.chan_block)
+        else:
+            y = ops.quantized_fastconv2d(
+                x, prep.wq, prep.act_scale, prep.w_scale, plan.algorithm,
+                padding=plan.spec.padding, bits=bits,
+                interpret=plan.interpret, k_block=cfg.k_block,
+                tile_block=cfg.tile_block, chan_block=cfg.chan_block)
+        return faults.maybe_corrupt(faults.APPLY_STAGED, y, detail=plan)
+
+    @staticmethod
+    def _fused_int8(plan, x, prep, cfg):
+        from repro.kernels.sfc_fused import sfc_fused_conv2d
+        faults.maybe_fault(faults.APPLY_FUSED, detail=plan)
+        y = sfc_fused_conv2d(
+            x, prep.wq, prep.act_scale, prep.w_scale, plan.algorithm,
+            padding=plan.spec.padding, bits=plan.spec.quant.bits_act,
+            interpret=plan.interpret, depthwise=plan.spec.depthwise,
+            k_block=cfg.k_block, cout_block=cfg.cout_block,
+            rows_per_step=cfg.rows_per_step,
+            double_buffer=cfg.double_buffer)
+        return faults.maybe_corrupt(faults.APPLY_FUSED, y, detail=plan)
+
+    @staticmethod
+    def _fp(plan, x, prep):
+        from repro.kernels import ops
         from repro.kernels.sfc_inverse import sfc_inverse
         from repro.kernels.sfc_transform import sfc_transform
+        algo = plan.algorithm
         tiles, geom = ops.extract_tiles(x, algo, plan.spec.padding)
         tx = sfc_transform(tiles, algo, interpret=plan.interpret)
-        if depthwise:
+        if plan.spec.depthwise:
             # transform-domain elementwise stage (tw (t, t, 1, C))
             ty = tx * prep.tw[:, :, 0, None, :].astype(x.dtype)
         else:
             ty = jnp.einsum("tunc,tuco->tuno", tx, prep.tw.astype(x.dtype),
                             precision=jax.lax.Precision.HIGHEST)
         y_tiles = sfc_inverse(ty, algo, interpret=plan.interpret)
-        return _add_bias(ops.untile(y_tiles, algo, geom), bias)
+        return ops.untile(y_tiles, algo, geom)
 
 
 _REFERENCE = ReferenceBackend()

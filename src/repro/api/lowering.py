@@ -55,6 +55,7 @@ import contextlib
 import dataclasses
 from typing import Any, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.api.plan import ConvPlan, PrepCache, PreparedWeights
@@ -301,6 +302,7 @@ class CompositePlan:
     # ------------------------------------------------------------------
     def apply(self, x, w, *, bias=None, elementwise_hook=None):
         """Run the lowered convolution; same contract as ``ConvPlan.apply``.
+        Sub-plan ``i`` runs under ``jax.named_scope(f"sub{i}")``.
 
         ``elementwise_hook`` is forwarded to every sub-plan that has a
         transform domain (fast or nested-lowered); direct sub-problems —
@@ -310,11 +312,14 @@ class CompositePlan:
         prep = w if isinstance(w, (PreparedWeights, CompositePrepared)) \
             else self.prepare_weights(w)
         y = None
-        for p, xs, pr in zip(self.sub_plans, self._sub_inputs(x), prep.subs):
-            if elementwise_hook is not None and p.path != "direct":
-                yi = p.apply(xs, pr, elementwise_hook=elementwise_hook)
-            else:
-                yi = p.apply(xs, pr)
+        for i, (p, xs, pr) in enumerate(zip(self.sub_plans,
+                                            self._sub_inputs(x), prep.subs)):
+            # HLO metadata only: a device trace names each sub-problem
+            with jax.named_scope(f"sub{i}"):
+                if elementwise_hook is not None and p.path != "direct":
+                    yi = p.apply(xs, pr, elementwise_hook=elementwise_hook)
+                else:
+                    yi = p.apply(xs, pr)
             if self.kind == "grouped":
                 y = [yi] if y is None else y + [yi]
             else:

@@ -226,6 +226,10 @@ class ConvPlan:
         the guardrail cannot inspect tracer values) and when an
         elementwise hook is passed (the hook's backend errors are
         contract errors, not kernel faults).
+
+        The backend runs the datapath it picks under
+        ``jax.named_scope("plan.<datapath>")`` (``fused``, ``staged``,
+        ``direct`` or ``reference``; ``repro.api.backends``).
         """
         from repro.api import backends, resilience  # late: avoids cycle
         prep = w if isinstance(w, PreparedWeights) else \

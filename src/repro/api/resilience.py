@@ -33,8 +33,7 @@ resilience story:
 
 The chain engages only on the ``pallas`` backend with no elementwise
 hook and never under tracing (``ConvPlan.apply`` gates it), and the
-healthy path costs one breaker lookup and a ``try`` — measured in
-``benchmarks/chaos.py``'s 0%-fault row against the PR 6 serving numbers.
+healthy path costs one breaker lookup and a ``try``.
 
 Observability: every event increments a process-wide counter *and* the
 thread-local metrics sink, so a serving engine attributes events from its

@@ -744,6 +744,7 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
             + strip_buf,
             compiler_params=_compiler_params(),
             interpret=interpret,
+            name="sfc_fused_depthwise",
         )(inv, scale, xp,
           jnp.pad(wq.reshape(P, C), ((0, 0), (0, geom.Cp - C))))
         return out[:, :out_h, :out_w, :C]
@@ -768,5 +769,6 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
         scratch_shapes=scratch_shapes + strip_buf,
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="sfc_fused_dense",
     )(inv, scale, xp, wqp)
     return out[:, :out_h, :out_w, :Cout]

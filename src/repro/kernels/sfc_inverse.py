@@ -61,5 +61,6 @@ def sfc_inverse(ty: jnp.ndarray, algo: BilinearAlgorithm, *,
                                lambda i, j: (0, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((M, M, nTp, Op), ty.dtype),
         interpret=resolve_interpret(interpret),
+        name="sfc_inverse",
     )(ty)
     return out[:, :, :nT, :O]

@@ -116,6 +116,7 @@ def tdmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
                                    lambda p, i, j: (p, i, j)),
             out_shape=jax.ShapeDtypeStruct((P, Tp, Np), jnp.float32),
             interpret=interpret,
+            name="sfc_tdmm",
         )(xq, wq, scale)
         return out[:, :T, :N]
     # k-blocked reduction: zero-padded K tail contributes nothing
@@ -139,6 +140,7 @@ def tdmm_int8(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((P, Tp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((t_block, n_block), jnp.int32)],
         interpret=interpret,
+        name="sfc_tdmm_kblock",
     )(xq, wq, scale)
     return out[:, :T, :N]
 
@@ -175,5 +177,6 @@ def tdmm_int8_depthwise(xq: jnp.ndarray, wq: jnp.ndarray, sx: jnp.ndarray,
                                lambda p, i, j: (p, i, j)),
         out_shape=jax.ShapeDtypeStruct((P, Tp, Cp), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="sfc_tdmm_depthwise",
     )(xq, wq_p, scale)
     return out[:, :T, :C]

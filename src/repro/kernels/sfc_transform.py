@@ -75,6 +75,7 @@ def _launch(kern, tiles, algo, out_dtype, extra_in, *, interpret,
                                lambda i, j: (0, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((t, t, nTp, Cp), out_dtype),
         interpret=resolve_interpret(interpret),
+        name="sfc_transform",
     )(*extra_in, tiles)
     return out[:, :, :nT, :C]
 

@@ -48,6 +48,18 @@ Every decision is counted in ``MetricsRegistry`` (``shed``,
 ``loop_errors``) and plan-level resilience events from this engine's
 dispatches land in the same registry via ``resilience.metrics_sink``.
 
+Spans (``repro.tracing``, on the engine's ``clock``): ``serve.loop``
+(the dispatch thread, start to stop) over ``serve.take`` (waiting for and
+forming a batch) and ``serve.dispatch`` (batch taken to its last future
+resolved), which holds ``serve.prep`` (pad, stack, cache lookup, fold),
+``serve.apply`` (host enqueue), ``serve.device_wait`` and
+``serve.resolve`` (crops, futures and their callbacks); ``serve.submit``
+on the caller's thread; and, per request, ``serve.queue`` from arrival to
+the batch being taken.  The engine's own stamps are those spans' stamps:
+``arrival_t`` is ``serve.submit``'s start, the dispatch time
+``serve.dispatch``'s start and the done time ``serve.device_wait``'s end,
+so ``Result.queue_wait_ms`` is exactly ``serve.queue``'s duration.
+
 Bit-identity: folding is the fused kernel's grouping dimension, which is
 bit-identical across group sizes (PR 4 invariant), and bucket padding is
 output-exact (``bucketing``) — so a batched engine answer equals the
@@ -57,6 +69,7 @@ bucket specs run under ``repro.testing.assert_conv_conformance``).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from concurrent.futures import Future
@@ -66,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import faults
+from repro import faults, tracing
 from repro.api import resilience
 from repro.api import serving_cache as sc
 from repro.serve.batcher import (AdmissionPolicy, Batch, BatchQueue,
@@ -116,6 +129,7 @@ class Engine:
         self._inflight_zero = threading.Condition()
         self._loop_errors = 0
         self._last_loop_error: Optional[BaseException] = None
+        self._batch_ids = itertools.count()
         # per-bucket provenance of the warm-up kernel config: 'measured'
         # (timing-cache entry), 'model' (cost-model prediction for a cold
         # bucket), or 'default' (kernel resolves its own)
@@ -123,6 +137,7 @@ class Engine:
         self._warm(calib_seed)
         if warm_compile:
             self._warm_compile()
+        self._compiles_at_warm = tracing.compiles()
 
     # ------------------------------------------------------------------
     # startup: warm every bucket off the request path
@@ -190,15 +205,17 @@ class Engine:
     def _warm_compile(self) -> None:
         """Trace/compile every (bucket, batch shape) dispatch off the
         request path: one zero-input dispatch per combination, routed
-        through the exact request-path code (fold config included), so
-        live traffic never pays a first-shape compile."""
+        through the exact request-path code (fold config and answer crops
+        included), so live traffic of bucket-shaped requests never pays a
+        first-shape compile."""
         for b in self.buckets.buckets:
             for s in self._batch_sizes():
                 reqs = [Request(x=jnp.zeros((b.h, b.w, b.spec.in_channels),
                                             jnp.float32),
                                 slo=BATCH, arrival_t=self.clock())
                         for _ in range(s)]
-                self._dispatch(Batch(bucket=b, requests=reqs), record=False)
+                self._dispatch(Batch(bucket=b, requests=reqs), self.clock(),
+                               record=False)
 
     # ------------------------------------------------------------------
     # request path
@@ -210,7 +227,12 @@ class Engine:
         :class:`RejectedError` — an open-loop client observes back
         pressure as failed futures, not blocked submits.
         """
-        req = Request(x=x, slo=slo, arrival_t=self.clock())
+        with tracing.span("serve.submit", clock=self.clock) as sp:
+            req = Request(x=x, slo=slo, arrival_t=sp.start)
+            sp.set(request_id=req.id)
+            return self._admit(req)
+
+    def _admit(self, req: Request) -> Future:
         self.metrics.inc("submitted")
         h, w = req.shape
         bucket = self.buckets.bucket_for(h, w)
@@ -252,16 +274,29 @@ class Engine:
         quarantine — ``step`` itself only raises on failures *outside*
         the serve path (e.g. batch formation), and even then every taken
         request's future is resolved first."""
-        batch = self.queue.take_batch(self.max_batch, timeout=timeout,
-                                      policy=self.scheduler)
+        with tracing.span("serve.take", clock=self.clock) as take:
+            batch = self.queue.take_batch(self.max_batch, timeout=timeout,
+                                          policy=self.scheduler)
+            if batch is not None:
+                take.set(n=len(batch), hold_ms=batch.hold_ms)
         if batch is None:
             return 0
         self.metrics.record_hold(batch.hold_ms)
         n = len(batch)
+        batch_id = next(self._batch_ids)
         try:
-            batch = self._shed_past_deadline(batch)
-            if batch.requests:
-                self._serve_batch(batch)
+            with tracing.span("serve.dispatch", clock=self.clock,
+                              batch_id=batch_id, n_real=n,
+                              n_padded=self._round_batch(n),
+                              request_ids=tuple(r.id for r in batch.requests)
+                              ) as disp:
+                for r in batch.requests:
+                    tracing.record("serve.queue", r.arrival_t, disp.start,
+                                   parent=disp, request_id=r.id,
+                                   batch_id=batch_id)
+                batch = self._shed_past_deadline(batch)
+                if batch.requests:
+                    self._serve_batch(batch, disp.start, batch_id)
         except Exception as e:             # resolve, don't wedge callers
             for r in batch.requests:
                 if not r.future.done():
@@ -293,7 +328,8 @@ class Engine:
                 kept.append(r)
         return Batch(bucket=batch.bucket, requests=kept)
 
-    def _serve_batch(self, batch: Batch) -> None:
+    def _serve_batch(self, batch: Batch, t_dispatch: float,
+                     batch_id: int) -> None:
         """Dispatch with bounded retry; on persistent failure, bisect the
         batch so one poison request cannot re-kill its co-batched peers.
         Never raises: a single request that still fails alone is resolved
@@ -308,7 +344,7 @@ class Engine:
             if attempt and self.retry_backoff_s > 0:
                 time.sleep(self.retry_backoff_s * (2 ** (attempt - 1)))
             try:
-                self._dispatch(batch)
+                self._dispatch(batch, t_dispatch, batch_id)
                 return
             except Exception as e:
                 err = e
@@ -327,76 +363,85 @@ class Engine:
         self.metrics.inc("batch_bisections")
         mid = len(pending) // 2
         self._serve_batch(Batch(bucket=batch.bucket,
-                                requests=pending[:mid]))
+                                requests=pending[:mid]), t_dispatch, batch_id)
         self._serve_batch(Batch(bucket=batch.bucket,
-                                requests=pending[mid:]))
+                                requests=pending[mid:]), t_dispatch, batch_id)
 
-    def _dispatch(self, batch: Batch, record: bool = True) -> None:
+    def _dispatch(self, batch: Batch, t_dispatch: float,
+                  batch_id: Optional[int] = None, record: bool = True
+                  ) -> None:
         if record:
             # warm-compile dispatches (record=False) are construction-time
             # plumbing, not traffic: an armed fault burst (times=...) must
             # fire under load, not be consumed warming the engine
             faults.maybe_fault(faults.DISPATCH, detail=batch)
         bucket = batch.bucket
-        t_dispatch = self.clock()
-        depth_after = self.queue.depth()
-        B_real = len(batch)
-        B = self._round_batch(B_real)
-        imgs_list = [BucketTable.pad_to(r.x, bucket)
-                     for r in batch.requests]
-        if B > B_real:
-            # round the batch shape up with zero images (outputs dropped):
-            # the compile-shape set stays bounded, per-image independence
-            # keeps every real output bit-identical
-            zero = jnp.zeros_like(imgs_list[0])
-            imgs_list += [zero] * (B - B_real)
-        xb = jnp.stack(imgs_list)
-        plan, prep = self.cache.get(
-            bucket.spec, self.w, backend=self.backend, algo=self.algo,
-            interpret=self.interpret,
-            act_scale=self._act_scales[bucket.name],
-            key=("serve", bucket.name))
-        fold = fold_rows_per_step(plan, B)
-        if fold is not None:
-            rows_per_step, imgs, _ = fold
-            run = plan.with_config(dataclasses.replace(
-                plan.config or _default_fused(),
-                rows_per_step=rows_per_step))
-        else:
-            imgs = 1
-            run = plan
+        clock = self.clock
+        with tracing.span("serve.prep", clock=clock, batch_id=batch_id):
+            depth_after = self.queue.depth()
+            B_real = len(batch)
+            B = self._round_batch(B_real)
+            imgs_list = [BucketTable.pad_to(r.x, bucket)
+                         for r in batch.requests]
+            if B > B_real:
+                # round the batch shape up with zero images (outputs
+                # dropped): the compile-shape set stays bounded, per-image
+                # independence keeps every real output bit-identical
+                zero = jnp.zeros_like(imgs_list[0])
+                imgs_list += [zero] * (B - B_real)
+            xb = jnp.stack(imgs_list)
+            plan, prep = self.cache.get(
+                bucket.spec, self.w, backend=self.backend, algo=self.algo,
+                interpret=self.interpret,
+                act_scale=self._act_scales[bucket.name],
+                key=("serve", bucket.name))
+            fold = fold_rows_per_step(plan, B)
+            if fold is not None:
+                rows_per_step, imgs, _ = fold
+                run = plan.with_config(dataclasses.replace(
+                    plan.config or _default_fused(),
+                    rows_per_step=rows_per_step))
+            else:
+                imgs = 1
+                run = plan
         # plan-level resilience events (fallbacks, breaker trips) raised
         # by THIS dispatch land in THIS engine's registry
         with resilience.metrics_sink(self.metrics.inc):
-            y = jax.block_until_ready(run.apply(xb, prep))
-        t_done = self.clock()
-        if not record:
-            return
-        service_ms = (t_done - t_dispatch) * 1e3
-        self.metrics.record_dispatch(
-            occupancy=B_real, imgs_per_step=imgs,
-            queue_depth=depth_after, service_ms=service_ms)
-        if B > B_real:
-            self.metrics.inc("batch_pad_imgs", B - B_real)
-        for i, r in enumerate(batch.requests):
-            if r.future.done():            # resolved on an earlier attempt
-                continue
-            r.t_dispatch, r.t_done = t_dispatch, t_done
-            h, w = r.shape
-            yi = BucketTable.crop_output(y[i], h, w, bucket)
-            queue_wait_ms = (t_dispatch - r.arrival_t) * 1e3
-            e2e_ms = (t_done - r.arrival_t) * 1e3
-            met = r.slo.met(e2e_ms)
-            self.metrics.record_request(
-                queue_wait_ms=queue_wait_ms, e2e_ms=e2e_ms,
-                slo_name=r.slo.name, met=met,
-                real_px=h * w, padded_px=bucket.h * bucket.w)
-            r.future.set_result(Result(
-                y=yi, request_id=r.id, bucket_name=bucket.name,
-                batch_size=len(batch), imgs_per_step=imgs,
-                queue_wait_ms=queue_wait_ms, service_ms=service_ms,
-                e2e_ms=e2e_ms, deadline_met=met,
-                pad_waste_frac=bucket.waste(h, w)))
+            with tracing.span("serve.apply", clock=clock, batch_id=batch_id):
+                y = run.apply(xb, prep)
+            with tracing.span("serve.device_wait", clock=clock,
+                              batch_id=batch_id) as wait:
+                y = jax.block_until_ready(y)
+        t_done = wait.end
+        with tracing.span("serve.resolve", clock=clock, batch_id=batch_id):
+            service_ms = (t_done - t_dispatch) * 1e3
+            if record:
+                self.metrics.record_dispatch(
+                    occupancy=B_real, imgs_per_step=imgs,
+                    queue_depth=depth_after, service_ms=service_ms)
+                if B > B_real:
+                    self.metrics.inc("batch_pad_imgs", B - B_real)
+            for i, r in enumerate(batch.requests):
+                if r.future.done():        # resolved on an earlier attempt
+                    continue
+                h, w = r.shape
+                yi = BucketTable.crop_output(y[i], h, w, bucket)
+                if not record:
+                    continue
+                r.t_dispatch, r.t_done = t_dispatch, t_done
+                queue_wait_ms = (t_dispatch - r.arrival_t) * 1e3
+                e2e_ms = (t_done - r.arrival_t) * 1e3
+                met = r.slo.met(e2e_ms)
+                self.metrics.record_request(
+                    queue_wait_ms=queue_wait_ms, e2e_ms=e2e_ms,
+                    slo_name=r.slo.name, met=met,
+                    real_px=h * w, padded_px=bucket.h * bucket.w)
+                r.future.set_result(Result(
+                    y=yi, request_id=r.id, bucket_name=bucket.name,
+                    batch_size=len(batch), imgs_per_step=imgs,
+                    queue_wait_ms=queue_wait_ms, service_ms=service_ms,
+                    e2e_ms=e2e_ms, deadline_met=met,
+                    pad_waste_frac=bucket.waste(h, w)))
 
     # ------------------------------------------------------------------
     # async dispatch thread
@@ -410,19 +455,21 @@ class Engine:
         self._running.set()
 
         def loop():
-            while self._running.is_set():
-                try:
-                    self.step(timeout=0.02)
-                except Exception as e:
-                    # the futures of the failed batch already carry the
-                    # error (``step`` resolves before re-raising); the
-                    # loop keeps serving — but the failure is COUNTED and
-                    # RETAINED, never silently dropped: ``loop_errors``
-                    # rides every snapshot and ``stop(raise_on_error=
-                    # True)`` re-raises the last one
-                    self._loop_errors += 1
-                    self._last_loop_error = e
-                    self.metrics.inc("loop_errors")
+            with tracing.span("serve.loop", clock=self.clock):
+                while self._running.is_set():
+                    try:
+                        self.step(timeout=0.02)
+                    except Exception as e:
+                        # the futures of the failed batch already carry
+                        # the error (``step`` resolves before re-raising);
+                        # the loop keeps serving — but the failure is
+                        # COUNTED and RETAINED, never silently dropped:
+                        # ``loop_errors`` rides every snapshot and
+                        # ``stop(raise_on_error=True)`` re-raises the
+                        # last one
+                        self._loop_errors += 1
+                        self._last_loop_error = e
+                        self.metrics.inc("loop_errors")
 
         self._thread = threading.Thread(target=loop, name="serve-dispatch",
                                         daemon=True)
@@ -458,7 +505,10 @@ class Engine:
     # ------------------------------------------------------------------
     def snapshot(self) -> Dict:
         """Metrics + serving-cache stats (with derived hit rate) in one
-        dict — the benchmark row source."""
+        dict — the benchmark row source.  ``compiles`` counts the backend
+        compiles since construction finished, by the ``serve.*`` span
+        they ran in (``repro.tracing``; counted process-wide, so another
+        engine's compiles in the same process count here too)."""
         snap = self.metrics.snapshot()
         cstats = self.cache.stats()
         lookups = cstats["hits"] + cstats["misses"]
@@ -474,6 +524,11 @@ class Engine:
         snap["last_loop_error"] = (repr(self._last_loop_error)
                                    if self._last_loop_error else None)
         snap["breakers"] = resilience.board_snapshot()
+        base = self._compiles_at_warm
+        snap["compiles"] = {
+            k: v - base.get(k, 0) for k, v in tracing.compiles().items()
+            if k is not None and k.startswith("serve.")
+            and v > base.get(k, 0)}
         return snap
 
     @property

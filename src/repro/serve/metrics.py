@@ -1,9 +1,10 @@
 """Streaming serving metrics: latency histograms, SLO attainment, queue
 depth, batch occupancy, padding waste.
 
-The registry is the engine's one accounting surface — every number the
-open-loop harness (``benchmarks/serving.py``) lands in
-``BENCH_conv.json["serving"]`` comes out of :meth:`MetricsRegistry.snapshot`.
+The registry is the engine's one accounting surface: ``Engine.snapshot()``
+returns :meth:`MetricsRegistry.snapshot` beside the serving cache's and
+the scheduler's state, and the on-chip benchmark (``bench/serve.py``)
+reads its batch occupancy from there.
 
 Histograms are *streaming*: geometric fixed buckets, O(1) memory per
 observation, percentiles by linear interpolation inside the bucket.  At
