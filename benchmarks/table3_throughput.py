@@ -14,10 +14,9 @@ machine-readable per-layer wall-clock sweep of the five datapaths
   staged  — three-kernel Pallas int8 pipeline (transform+quant / tdmm /
             inverse, two HBM round-trips of the transform-domain tensor)
   fused   — single-``pallas_call`` int8 pipeline (``sfc_fused``),
-            one tile-row per grid step
-  batched — the fused kernel with the multi-tile-row grid
-            (``rows_per_step=None``: VMEM-budget auto grouping) — the
-            small-image variant ROADMAP calls for
+            one tile-row per grid step (``rows_per_step=1``)
+  batched — the fused kernel at its default, shape-resolved grouping
+            (tile-rows, then whole images, folded per grid step)
   int8    — reference-backend static-int8 simulation (jnp)
 
 plus the ``resnet_lowered`` rows: ResNet-18's stride-2 stem and stage
@@ -44,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import ConvSpec, get_algorithm, plan
-from repro.api.tuning import (DEFAULT_BATCHED, DEFAULT_FUSED, DEFAULT_STAGED,
+from repro.api.tuning import (DEFAULT_FUSED, DEFAULT_STAGED, KernelConfig,
                               calibrate_act_scale, time_fn)
 from repro.quant import ConvWorkload, bops_reduction, INT8_FREQ
 
@@ -100,12 +99,12 @@ def _layer_sweep(layers, algo_name: str, reps: int, log) -> list:
         fns = {
             "direct": jax.jit(lambda a: p_direct.apply(a, w)),
             "fused": jax.jit(
-                lambda a, _p=dataclasses.replace(p_fused,
-                                                 config=DEFAULT_FUSED):
+                lambda a, _p=dataclasses.replace(
+                    p_fused, config=KernelConfig(rows_per_step=1)):
                 _p.apply(a, prep)),
             "batched": jax.jit(
                 lambda a, _p=dataclasses.replace(p_fused,
-                                                 config=DEFAULT_BATCHED):
+                                                 config=DEFAULT_FUSED):
                 _p.apply(a, prep)),
             "staged": jax.jit(
                 lambda a, _p=dataclasses.replace(p_fused,

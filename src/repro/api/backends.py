@@ -26,16 +26,20 @@ Each backend runs the datapath it chose under
 ``jax.named_scope("plan.<datapath>")`` (``fused``, ``staged``, ``direct``
 or ``reference``), so a device trace names the datapath of every conv
 op, a resilience fallback level included.  The scope is HLO metadata
-only.
+only.  A fused launch traced into a program also appends one
+buffer-only ``kernels.fused_grouping`` record (:mod:`repro.tracing`)
+with the grouping the kernel resolves: ``imgs``, ``rows``, ``cols`` and
+``grid_steps``; an eager launch records nothing.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro import faults
+from repro import faults, tracing
 from repro.core import conv2d as c2d
 import repro.quant.fake_quant as fq
 
@@ -188,15 +192,26 @@ class PallasBackend:
 
     @staticmethod
     def _fused_int8(plan, x, prep, cfg):
-        from repro.kernels.sfc_fused import sfc_fused_conv2d
+        from repro.kernels.sfc_fused import fused_geometry, sfc_fused_conv2d
         faults.maybe_fault(faults.APPLY_FUSED, detail=plan)
+        launch = dict(padding=plan.spec.padding,
+                      depthwise=plan.spec.depthwise, k_block=cfg.k_block,
+                      cout_block=cfg.cout_block,
+                      rows_per_step=cfg.rows_per_step,
+                      double_buffer=cfg.double_buffer)
+        if isinstance(x, jax.core.Tracer):
+            # one record per launch traced into a program, made here: the
+            # kernel's own jit traces a repeated shape only once
+            g = fused_geometry(plan.algorithm, *x.shape,
+                               prep.wq.shape[2], **launch)
+            now = time.perf_counter()
+            tracing.record("kernels.fused_grouping", now, now, imgs=g.imgs,
+                           rows=g.rows, cols=g.cols,
+                           grid_steps=g.grid_steps)
         y = sfc_fused_conv2d(
             x, prep.wq, prep.act_scale, prep.w_scale, plan.algorithm,
-            padding=plan.spec.padding, bits=plan.spec.quant.bits_act,
-            interpret=plan.interpret, depthwise=plan.spec.depthwise,
-            k_block=cfg.k_block, cout_block=cfg.cout_block,
-            rows_per_step=cfg.rows_per_step,
-            double_buffer=cfg.double_buffer)
+            bits=plan.spec.quant.bits_act, interpret=plan.interpret,
+            **launch)
         return faults.maybe_corrupt(faults.APPLY_FUSED, y, detail=plan)
 
     @staticmethod

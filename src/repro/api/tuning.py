@@ -52,8 +52,10 @@ class KernelConfig:
     k_block: Optional[int] = 128  # C_in reduction block (None = full K)
     cout_block: int = 128         # fused C_out block
     # fused grid batching: tile-rows (then whole images) folded per grid
-    # step; None = auto via sfc_fused.auto_rows_per_step's VMEM budget
-    rows_per_step: Optional[int] = 1
+    # step; None resolves from the launch shape (sfc_fused.
+    # auto_rows_per_step: the fewest grid steps the VMEM budget allows);
+    # an explicit value (the serving fold's) wins
+    rows_per_step: Optional[int] = None
     # fused DMA pipelining: prefetch the next input strip group into a
     # second VMEM slot while the current one is transformed and matmul'd
     double_buffer: bool = False
@@ -71,21 +73,17 @@ class KernelConfig:
 
 DEFAULT_FUSED = KernelConfig()
 DEFAULT_STAGED = KernelConfig(datapath="staged", k_block=None)
-# the batched/pipelined small-image variant (ROADMAP: multi-tile-row grid
-# + double-buffered strips); rows_per_step=None resolves per shape
-DEFAULT_BATCHED = KernelConfig(datapath="fused", rows_per_step=None)
 
 # default candidate sweep: the fused datapath at a few block shapes
-# (including full-K: single k-block, no reduction grid dim), the batched
-# multi-tile-row grid with and without DMA double-buffering, plus the
-# staged pipeline (full-K and k-blocked) as fallback candidates
+# (including full-K: single k-block, no reduction grid dim), each at the
+# shape-resolved grouping, with and without DMA double-buffering, plus
+# the staged pipeline (full-K and k-blocked) as fallback candidates
 DEFAULT_CANDIDATES = (
-    KernelConfig(datapath="fused", k_block=128, cout_block=128),
+    DEFAULT_FUSED,
     KernelConfig(datapath="fused", k_block=256, cout_block=128),
     KernelConfig(datapath="fused", k_block=128, cout_block=256),
     KernelConfig(datapath="fused", k_block=None),
-    KernelConfig(datapath="fused", rows_per_step=None),
-    KernelConfig(datapath="fused", rows_per_step=None, double_buffer=True),
+    KernelConfig(datapath="fused", double_buffer=True),
     KernelConfig(datapath="staged", k_block=None),
     KernelConfig(datapath="staged", k_block=128),
 )

@@ -37,12 +37,18 @@ The transform-domain tensor therefore never touches HBM.
 Grouping (``rows_per_step``): ``rows = min(rows_per_step, nH)`` tile-rows
 of one image fold into a step; when ``rows_per_step >= nH`` the leftover
 factor folds whole images (``imgs = rows_per_step // nH``, clamped to a
-divisor of B so no padded images are computed).  ``rows_per_step=None``
-resolves via :func:`auto_rows_per_step`, the largest candidate whose
-per-step footprint (:func:`fused_vmem_bytes`, the budget math below) fits
-``VMEM_LIMIT_BYTES``.  All groupings are bit-identical to
-``rows_per_step=1``: the per-strip arithmetic and the per-column matmul
-contraction are unchanged, only the grid batching differs.
+divisor of B so no padded images are computed).  ``rows_per_step=None``,
+the default, resolves from the launch shape via :func:`auto_rows_per_step`:
+the grouping with the fewest grid steps whose per-step footprint
+(:func:`fused_vmem_bytes`, the budget math below) fits
+``VMEM_LIMIT_BYTES``, with no more padded tile-rows than that step count
+needs.  A step costs a fixed ~10 us (t^2 weight blocks through the MXU,
+loop and accumulator overhead) plus work per tile column, so fewer,
+fuller steps win on a TPU v5e even past the MXU's 128 rows and past the
+point where the xq cache no longer fits (PERF.md §5).  All groupings are
+bit-identical to ``rows_per_step=1``: the per-strip arithmetic and the
+per-column matmul contraction are unchanged, only the grid batching
+differs.
 
 VMEM budget per grid step (f32 in, defaults K_BLOCK=COUT_BLOCK=128, the
 VGG-16 224x224 worst case with SFC-6(7x7,3x3): L=9, t=12, nW=32, Wp=226,
@@ -92,8 +98,6 @@ XQ_CACHE_BYTES = 4 * 1024 * 1024
 # default scoped grant is 16 MiB, so the grant is passed explicitly)
 VMEM_LIMIT_BYTES = 24 * 1024 * 1024
 VMEM_HEADROOM_BYTES = 8 * 1024 * 1024
-# candidate group sizes auto_rows_per_step tries, largest first
-AUTO_ROWS_CANDIDATES = (8, 4, 2, 1)
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -166,21 +170,31 @@ def fused_vmem_bytes(algo: BilinearAlgorithm, n_w: int, w_padded: int,
 def auto_rows_per_step(algo: BilinearAlgorithm, B: int, nH: int, n_w: int,
                        w_padded: int, kb: int, cb: int, *, n_k: int = 1,
                        n_o: int = 1, double_buffer: bool = False) -> int:
-    """Largest AUTO_ROWS_CANDIDATES group whose step fits the VMEM budget.
+    """The budget-fitting grouping with the fewest grid steps.
 
-    Falls back to 1 (the ungrouped grid, which the docstring's worst case
-    shows fits at the default block sizes).
+    Candidates fold tile-rows of one image first — for each count of
+    strip groups per image, the fewest rows that reach it, so a step
+    computes no padded tile-row it can avoid — then whole images,
+    divisors of B only.  The smallest candidate of the fewest steps
+    whose footprint fits ``VMEM_LIMIT_BYTES`` wins; 1 (the ungrouped
+    grid, which the docstring's worst case shows fits at the default
+    block sizes) when none does.
     """
-    for g in AUTO_ROWS_CANDIDATES:
+    def steps(g: int) -> int:
         imgs, rows = grouping(B, nH, g)
-        cols = imgs * rows * n_w
-        cache = cache_fits(n_o, n_k, algo.t ** 2, cols, kb)
-        if fused_vmem_bytes(algo, n_w, w_padded, kb, cb, n_k=n_k,
-                            rows=rows, imgs=imgs, cache_xq=cache,
-                            double_buffer=double_buffer) \
-                <= VMEM_LIMIT_BYTES:
-            return g
-    return 1
+        return (B // imgs) * -(-nH // rows)
+
+    def fits(g: int) -> bool:
+        imgs, rows = grouping(B, nH, g)
+        cache = cache_fits(n_o, n_k, algo.t ** 2, imgs * rows * n_w, kb)
+        return fused_vmem_bytes(algo, n_w, w_padded, kb, cb, n_k=n_k,
+                                rows=rows, imgs=imgs, cache_xq=cache,
+                                double_buffer=double_buffer) \
+            <= VMEM_LIMIT_BYTES
+
+    rows = sorted({-(-nH // g_h) for g_h in range(1, nH + 1)})
+    cands = rows + [d * nH for d in range(2, B + 1) if B % d == 0]
+    return min((g for g in cands if fits(g)), key=steps, default=1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -406,7 +420,7 @@ def fused_geometry(algo: BilinearAlgorithm, B: int, H: int, W: int,
                    C: int, Cout: int, *, padding: str = "SAME",
                    k_block: Optional[int] = K_BLOCK,
                    cout_block: int = COUT_BLOCK,
-                   rows_per_step: Optional[int] = 1,
+                   rows_per_step: Optional[int] = None,
                    double_buffer: bool = False,
                    depthwise: bool = False) -> FusedGeometry:
     """Resolve the launch geometry :func:`sfc_fused_conv2d` will use.
@@ -662,7 +676,7 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
                      interpret: Optional[bool] = None,
                      k_block: Optional[int] = K_BLOCK,
                      cout_block: int = COUT_BLOCK,
-                     rows_per_step: Optional[int] = 1,
+                     rows_per_step: Optional[int] = None,
                      double_buffer: bool = False,
                      depthwise: bool = False) -> jnp.ndarray:
     """int8 SFC convolution in one ``pallas_call``.
@@ -675,8 +689,8 @@ def sfc_fused_conv2d(x: jnp.ndarray, wq: jnp.ndarray,
     ``k_block=None`` means full K: the whole C_in reduction in a single
     k-block (``n_k = 1``).  ``rows_per_step`` folds that many tile-rows
     (counting across images once one image's rows are exhausted — see
-    :func:`grouping`) into a single grid step; ``None`` picks the largest
-    budget-fitting group via :func:`auto_rows_per_step`.
+    :func:`grouping`) into a single grid step; ``None`` (the default)
+    resolves it from the shape via :func:`auto_rows_per_step`.
     ``double_buffer`` prefetches the next strip group into a second VMEM
     slot while the current one is transformed and matmul'd.
     ``interpret=None`` compiles for a TPU and interprets elsewhere

@@ -35,8 +35,8 @@ def test_real_geometries_are_clean():
 
 def test_geometry_matches_kernel_docstring_values():
     # hand-derived reference launch from the sfc_fused docstring/smoke:
-    # B=2 12x12 16->24 with sfc4_4 (M=4, t=7)
-    geom = sf.fused_geometry(ALGO, 2, 12, 12, 16, 24)
+    # B=2 12x12 16->24 with sfc4_4 (M=4, t=7), one tile-row a step
+    geom = sf.fused_geometry(ALGO, 2, 12, 12, 16, 24, rows_per_step=1)
     assert geom.grid == (6, 1, 1)
     # the strip is padded to the chip's tiling: 14 -> 16 columns (8
     # sublanes), 16 -> 128 input channels (lanes)
@@ -46,7 +46,8 @@ def test_geometry_matches_kernel_docstring_values():
         ("acc", (49, 3, 24), "int32"), ("stage", (49, 3, 128), "float32"),
         ("strip_buf", (1, 1, 6, 16, 128), "float32"))
     assert geom.rmw_axis == 2
-    dw = sf.fused_geometry(ALGO, 2, 8, 8, 20, 20, depthwise=True)
+    dw = sf.fused_geometry(ALGO, 2, 8, 8, 20, 20, rows_per_step=1,
+                           depthwise=True)
     assert dw.grid == (4, 1)
     assert dw.kb == dw.cb == 128 and dw.n_k == 1
     assert dw.scratch_shapes() == (

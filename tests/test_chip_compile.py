@@ -16,10 +16,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.api import registry
 from repro.kernels import ops
+from repro.kernels import sfc_fused as sf
 from repro.kernels.sfc_fused import sfc_fused_conv2d
 
 ALGO = registry.get_algorithm("sfc6_6")
-BATCH = 8
 
 
 @pytest.fixture(scope="module")
@@ -51,26 +51,36 @@ def _staged(x, wq, act, ws):
 
 
 CASES = {
-    # name: (launch, H=W, C_in, C_out, depthwise)
-    "fused_56_128_256": (_fused(), 56, 128, 256, False),
-    "fused_28_512_512": (_fused(), 28, 512, 512, False),
-    "fused_224_64_64": (_fused(), 224, 64, 64, False),
-    "fused_dw_28_256": (_fused(depthwise=True), 28, 256, 256, True),
-    "fused_db_28_512_512": (_fused(rows_per_step=None, double_buffer=True),
-                            28, 512, 512, False),
-    "staged_56_128_256": (_staged, 56, 128, 256, False),
+    # name: (launch, batch, H=W, C_in, C_out, depthwise); every fused
+    # launch without rows_per_step takes the shape-resolved grouping
+    "fused_56_128_256": (_fused(), 8, 56, 128, 256, False),
+    "fused_28_512_512": (_fused(), 8, 28, 512, 512, False),
+    "fused_224_64_64": (_fused(), 8, 224, 64, 64, False),
+    "fused_dw_28_256": (_fused(depthwise=True), 8, 28, 256, 256, True),
+    "fused_db_28_512_512": (_fused(double_buffer=True), 8, 28, 512, 512,
+                            False),
+    "staged_56_128_256": (_staged, 8, 56, 128, 256, False),
+    # VGG-16 at b32: 28x28 folds 4 whole images a step, 14x14 folds 8
+    "fused_b32_28_512_512": (_fused(), 32, 28, 512, 512, False),
+    "fused_b32_14_512_512": (_fused(), 32, 14, 512, 512, False),
 }
+# whole images the default grouping folds per step, where a case is
+# there to rehearse it
+FOLDS = {"fused_b32_28_512_512": 4, "fused_b32_14_512_512": 8}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, case):
-    launch, hw, cin, cout, dw = CASES[case]
+    launch, batch, hw, cin, cout, dw = CASES[case]
     t, P = ALGO.t, ALGO.t ** 2
+    if case in FOLDS:
+        assert sf.fused_geometry(ALGO, batch, hw, hw, cin, cout).imgs \
+            == FOLDS[case]
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = (spec((BATCH, hw, hw, cin), jnp.float32),
+    args = (spec((batch, hw, hw, cin), jnp.float32),
             spec((P, 1 if dw else cin, cout), jnp.int8),
             spec((t, t), jnp.float32),
             spec((t, t, cout), jnp.float32))

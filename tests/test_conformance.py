@@ -26,7 +26,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.api import ConvSpec
+from repro.api import ConvSpec, plan
 from repro.core.generator import generate_sfc
 from repro.kernels import sfc_fused as sf
 from repro.quant.fake_quant import FP32, INT4_FREQ, INT8_FREQ
@@ -71,15 +71,20 @@ def test_conformance_core(algo_name, padding):
     ((1, 17, 13, 19), 21, 4),   # C_in/C_out not block multiples
     ((4, 7, 7, 3), 5, 4),       # nH < rows_per_step: folds whole images
     ((3, 6, 6, 9), 4, 8),       # group exceeds B*nH: clamps to divisors
+    ((8, 14, 14, 4), 8, None),  # the default grouping folds whole images
 ])
 def test_conformance_ragged_and_folded(shape, cout, rps):
     x, w = _case(*shape, cout, seed=1)
     spec = ConvSpec.for_conv2d(x.shape, w.shape, quant=INT8_FREQ)
-    assert_conv_conformance(
-        x, w, spec, "sfc6_6",
-        variants=(dict(k_block=None, rows_per_step=rps),
-                  dict(k_block=8, cout_block=16, rows_per_step=rps,
-                       double_buffer=True)))
+    variants = (dict(k_block=None, rows_per_step=rps),
+                dict(k_block=8, cout_block=16, rows_per_step=rps,
+                     double_buffer=True))
+    if rps is None:
+        algo = plan(spec, backend="pallas", algo="sfc6_6").algorithm
+        assert sf.fused_geometry(algo, *shape, cout).imgs > 1
+        # the default config, bit for bit the ungrouped grid
+        variants += (dict(), dict(rows_per_step=1))
+    assert_conv_conformance(x, w, spec, "sfc6_6", variants=variants)
 
 
 def test_conformance_fp_and_direct_paths():
@@ -159,7 +164,83 @@ def test_auto_rows_never_exceeds_budget():
             algo, nW, Wp, 128, 128, n_k=4, rows=rows, imgs=imgs,
             cache_xq=cache) <= sf.VMEM_LIMIT_BYTES
         if nH <= 2 and B == 1:
-            assert g >= 2, "small images must batch tile-rows"
+            assert rows == nH, "small images must batch tile-rows"
+
+
+# VGG-16's 3x3/1 convs at 224x224 (name, H=W, C_in, C_out), run at b32
+VGG16 = [("s0c0", 224, 3, 64), ("s0c1", 224, 64, 64),
+         ("s1c0", 112, 64, 128), ("s1c1", 112, 128, 128),
+         ("s2c0", 56, 128, 256), ("s2c1", 56, 256, 256),
+         ("s2c2", 56, 256, 256), ("s3c0", 28, 256, 512),
+         ("s3c1", 28, 512, 512), ("s3c2", 28, 512, 512),
+         ("s4c0", 14, 512, 512), ("s4c1", 14, 512, 512),
+         ("s4c2", 14, 512, 512)]
+# ResNet-18's convs with a fused launch (name, H=W, C_in, C_out, kernel,
+# stride), run at b1: the 7x7/2 stem and the 3x3/2 transitions lower to
+# fused sub-plans
+RESNET18 = [("stem", 224, 3, 64, 7, 2)] + [
+    (f"s0b{b}.conv{c}", 56, 64, 64, 3, 1) for b in (0, 1) for c in (1, 2)
+] + [layer for si, (hw, cin, cout) in enumerate(
+    [(56, 64, 128), (28, 128, 256), (14, 256, 512)], start=1)
+    for layer in [(f"s{si}b0.conv1", hw, cin, cout, 3, 2)]
+    + [(f"s{si}b{b}.conv{c}", hw // 2, cout, cout, 3, 1)
+       for b, c in ((0, 2), (1, 1), (1, 2))]]
+
+
+def _fused_launches(B, hw, cin, cout, kernel=3, stride=1):
+    """(algorithm, B, H, W, C_in, C_out, padding) of each fused launch of
+    the layer's plan, lowered sub-plans included."""
+    spec = ConvSpec.for_conv2d((B, hw, hw, cin),
+                               (kernel, kernel, cin, cout), stride=stride,
+                               quant=INT8_FREQ)
+    p = plan(spec, backend="pallas",
+             algo="sfc6_6" if (kernel, stride) == (3, 1) else "auto")
+    subs = p.sub_plans if p.path == "lowered" else [p]
+    out = [(sp.algorithm, B) + sp.spec.spatial
+           + (sp.spec.in_channels, sp.spec.out_channels, sp.spec.padding)
+           for sp in subs if sp.path == "fast"]
+    assert out, p
+    return out
+
+
+@pytest.mark.parametrize(
+    "B,layer", [(32, l) for l in VGG16] + [(1, l) for l in RESNET18],
+    ids=[f"vgg16-b32-{l[0]}" for l in VGG16]
+    + [f"resnet18-b1-{l[0]}" for l in RESNET18])
+def test_default_grouping_policy_on_real_launches(B, layer):
+    """The default grouping of every fused launch of VGG-16 at b32 and
+    ResNet-18 at b1: it fits the budget, folds only divisors of B, fills
+    the MXU's 128 rows or is the widest grouping that fits, and no
+    fitting grouping takes fewer grid steps or computes fewer padded
+    tile-rows in as many."""
+    for algo, B_, H, W, C, Cout, pad in _fused_launches(B, *layer[1:]):
+        g = sf.fused_geometry(algo, B_, H, W, C, Cout, padding=pad)
+        fits = [f for f in (sf.fused_geometry(algo, B_, H, W, C, Cout,
+                                              padding=pad, rows_per_step=r)
+                            for r in range(1, B_ * g.nH + 1))
+                if f.vmem_bytes() <= sf.VMEM_LIMIT_BYTES]
+        assert g.vmem_bytes() <= sf.VMEM_LIMIT_BYTES
+        assert B_ % g.imgs == 0
+        assert g.cols >= 128 or g.cols == max(f.cols for f in fits)
+        assert g.grid_steps == min(f.grid_steps for f in fits)
+        assert g.imgs * g.nH_p == min(f.imgs * f.nH_p for f in fits
+                                      if f.grid_steps == g.grid_steps)
+
+
+# the serving fold of conv3_2 (256 -> 256) per bucket and batch 1..8,
+# as the grouping default before it resolved from the shape left it
+SERVE_FOLD = {56: [(10, 1, 10)] * 8, 48: [(8, 1, 8)] * 8,
+              40: [(7, 1, 7), (14, 2, 7)] * 4}
+
+
+@pytest.mark.parametrize("hw", sorted(SERVE_FOLD))
+def test_serving_fold_ignores_the_default_grouping(hw):
+    from repro.serve import batcher
+    spec = ConvSpec.for_conv2d((1, hw, hw, 256), (3, 3, 256, 256),
+                               quant=INT8_FREQ)
+    p = plan(spec, backend="pallas", algo="sfc6_6")
+    assert [batcher.fold_rows_per_step(p, b) for b in range(1, 9)] \
+        == SERVE_FOLD[hw]
 
 
 def test_grouping_folds_only_divisor_images():

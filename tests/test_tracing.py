@@ -231,6 +231,41 @@ def test_plan_and_sub_plan_scopes_reach_the_hlo():
                for n in names if "/sub" in n)
 
 
+def test_every_fused_launch_of_a_traced_forward_records_its_grouping():
+    """One ``kernels.fused_grouping`` record per fused launch, repeated
+    shapes and lowered sub-plans included; an eager launch records
+    nothing."""
+    from repro.kernels import sfc_fused as sf
+    rng = np.random.RandomState(8)
+    x = jnp.asarray(rng.randn(4, 12, 12, 4), jnp.float32)
+    w = jnp.asarray(rng.randn(3, 3, 4, 4) * 0.2, jnp.float32)
+    spec = ConvSpec.for_conv2d(x.shape, w.shape, quant=INT8_FREQ)
+    dense = plan(spec, backend="pallas", algo="sfc6_6")
+    prep = dense.prepare_weights(w, act_scale=tuning.calibrate_act_scale(
+        x, dense.algorithm, spec.quant, spec.padding))
+    spec2 = ConvSpec.for_conv2d(x.shape, w.shape, stride=2, quant=INT8_FREQ)
+    lowered = plan(spec2, backend="pallas", algo="sfc4_4_r2")
+    prep2 = lowered.prepare_weights(w, act_scale=lowered.calibrate(x))
+    fast_subs = [sp for sp in lowered.sub_plans if sp.path == "fast"]
+    assert fast_subs
+
+    def forward(x):
+        # the same shape twice: the kernel's jit traces it once
+        return lowered.apply(dense.apply(dense.apply(x, prep), prep), prep2)
+
+    tracing.reset()
+    jax.jit(forward).lower(x)
+    recs = tracing.spans("kernels.fused_grouping")
+    assert len(recs) == 2 + len(fast_subs)
+    g = sf.fused_geometry(dense.algorithm, 4, 12, 12, 4, 4)
+    assert recs[0].attrs == recs[1].attrs == dict(
+        imgs=g.imgs, rows=g.rows, cols=g.cols, grid_steps=g.grid_steps)
+    assert g.imgs == 4                     # small maps fold whole images
+    tracing.reset()
+    jax.block_until_ready(dense.apply(x, prep))
+    assert tracing.spans("kernels.fused_grouping") == []
+
+
 # ----------------------------------------------------------------------
 # the benchmark's readers of the engine spans
 # ----------------------------------------------------------------------
