@@ -2,7 +2,14 @@
 then plan, calibrate, prepare and apply a conv layer through ``repro.api``.
 
 A layer is a dict: ``name``, ``kernel``, ``stride``, ``cin``, ``cout``,
-``h``/``w`` (its input extent) and ``algo`` (the ``plan()`` request).
+``h``/``w`` (its input extent), ``algo`` (the ``plan()`` request) and,
+optionally, ``groups`` (default 1; ``cin`` and ``cout`` both divide by
+it).  Each group convolves ``cin // groups`` input channels into
+``cout // groups`` outputs, and the weights are ``lax``'s grouped HWIO,
+``(kernel, kernel, cin // groups, cout)``.  A layer is depthwise when
+``groups == cin == cout`` (more than one channel), which is read from
+the shape: there is no separate key for it.
+
 Prepared weights cross ``jax.jit`` as plain dicts of arrays
 (:func:`prepared_arrays` / :func:`apply`), so that one jitted call
 prepares every layer and the forward takes them as arguments rather than
@@ -15,6 +22,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from bench.work import groups
 from repro.api import ConvSpec, plan
 from repro.api.lowering import CompositePrepared
 from repro.api.plan import PreparedWeights
@@ -35,6 +43,10 @@ def quant_config(q: Dict) -> QuantConfig:
                        q["act_granularity"], q["weight_granularity"])
 
 
+def is_depthwise(layer: Dict) -> bool:
+    return 1 < groups(layer) == layer["cin"] == layer["cout"]
+
+
 def make_params(key, layers: Sequence[Dict], head: Optional[Dict] = None):
     """He-normal conv weights and zero biases for ``layers`` (layer i from
     ``fold_in(key, i)``, so a layer's weights do not depend on which other
@@ -44,9 +56,10 @@ def make_params(key, layers: Sequence[Dict], head: Optional[Dict] = None):
     def make(key):
         p = {}
         for l in layers:
-            fan = l["kernel"] ** 2 * l["cin"]
+            cin = l["cin"] // groups(l)
+            fan = l["kernel"] ** 2 * cin
             w = jax.random.normal(jax.random.fold_in(key, l["index"]),
-                                  (l["kernel"], l["kernel"], l["cin"],
+                                  (l["kernel"], l["kernel"], cin,
                                    l["cout"]), jnp.float32)
             p[l["name"]] = {"w": w * jnp.sqrt(2.0 / fan),
                             "b": jnp.zeros((l["cout"],), jnp.float32)}
@@ -68,10 +81,17 @@ def make_images(key, shapes: Sequence[tuple]) -> List[jax.Array]:
 
 
 def plan_layer(layer: Dict, quant: QuantConfig):
-    spec = ConvSpec.for_conv2d(
-        (1, layer["h"], layer["w"], layer["cin"]),
-        (layer["kernel"], layer["kernel"], layer["cin"], layer["cout"]),
-        stride=layer["stride"], quant=quant)
+    """The layer's plan: a depthwise spec for a depthwise layer, a grouped
+    one for any other with ``groups`` > 1."""
+    k, g = layer["kernel"], groups(layer)
+    x_shape = (1, layer["h"], layer["w"], layer["cin"])
+    w_shape = (k, k, layer["cin"] // g, layer["cout"])
+    if is_depthwise(layer):
+        spec = ConvSpec.for_conv2d_depthwise(
+            x_shape, w_shape, stride=layer["stride"], quant=quant)
+    else:
+        spec = ConvSpec.for_conv2d(x_shape, w_shape, stride=layer["stride"],
+                                   groups=g, quant=quant)
     return plan(spec, backend="pallas", algo=layer["algo"])
 
 
@@ -117,8 +137,13 @@ def apply(p, arrays, x, bias):
 
 
 def conv_layers(family, cfg: Dict) -> List[Dict]:
-    """The family's conv layers, each with its position ``index``."""
+    """The family's conv layers, each with its position ``index``; a layer
+    whose channels do not divide by its ``groups`` raises."""
     out = family.layers(cfg)
     for i, l in enumerate(out):
+        g = groups(l)
+        if g < 1 or l["cin"] % g or l["cout"] % g:
+            raise ValueError(f"layer {l['name']}: cin {l['cin']} and cout "
+                             f"{l['cout']} must both divide by groups {g}")
         l["index"] = i
     return out

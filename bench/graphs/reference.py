@@ -10,11 +10,13 @@ import jax.numpy as jnp
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def conv(x, w, b, stride: int):
-    """SAME-padded NHWC/HWIO convolution plus bias, in f32 at HIGHEST."""
+def conv(x, w, b, stride: int, groups: int = 1):
+    """SAME-padded NHWC/HWIO convolution plus bias, in f32 at HIGHEST;
+    with ``groups`` > 1 grouped, ``w`` of shape (R, R, C_in/groups, C_out)."""
     y = jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST)
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST,
+        feature_group_count=groups)
     return y + b
 
 

@@ -51,8 +51,9 @@ def reference_fn(cfg: Dict, family, convs):
         def conv(name, hh):
             if record is not None:
                 record[name] = hh
-            y = ref.conv(hh, p[name]["w"], p[name]["b"],
-                         by_name[name]["stride"])
+            layer = by_name[name]
+            y = ref.conv(hh, p[name]["w"], p[name]["b"], layer["stride"],
+                         work.groups(layer))
             taps[name] = y[:1]
             return y
         out = family.network(cfg, conv, lambda hh: ref.dense(
